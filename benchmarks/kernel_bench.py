@@ -1,6 +1,7 @@
 """Kernel micro-benchmarks: us/call for the policy-plane hot spot (the
-argmin-over-TTLs scan) at production scale, Pallas interpret vs numpy oracle,
-plus the CPU-side simulator throughput."""
+argmin-over-TTLs scan) at production scale, Pallas kernel (compiled on a
+TPU, interpreted elsewhere) vs the jnp oracle, plus the CPU-side simulator
+throughput."""
 
 from __future__ import annotations
 
@@ -13,7 +14,9 @@ from repro.core.histogram import cell_edges
 from repro.kernels import ttl_scan
 
 
-def _problem(e_dim: int, seed=0):
+def ttl_problem(e_dim: int, seed=0):
+    """A seeded ``e_dim`` x 800-cell TTL problem: the arguments of
+    :func:`repro.kernels.ttl_scan` (float32 histograms, float64 cells)."""
     rng = np.random.default_rng(seed)
     c = 800
     edges = cell_edges()
@@ -37,15 +40,16 @@ def ttl_scan_bench(e_dim: int = 1024, iters: int = 3):
     an interpret-mode timing off as a hardware measurement."""
     import jax
 
-    prob = _problem(e_dim)
+    prob = ttl_problem(e_dim)
     backend = jax.default_backend()
     compiled = backend == "tpu"
     out = {}
     for use_kernel, label in ((False, "jnp_oracle"), (True, "pallas")):
-        ttl_scan(*prob, use_kernel=use_kernel)      # warm/compile
+        kw = dict(use_kernel=use_kernel, interpret=not compiled)
+        ttl_scan(*prob, **kw)      # warm/compile
         t0 = time.perf_counter()
         for _ in range(iters):
-            r = ttl_scan(*prob, use_kernel=use_kernel)
+            r = ttl_scan(*prob, **kw)
             r[0].block_until_ready()
         out[label] = (time.perf_counter() - t0) / iters * 1e6
     out["edges_per_refresh"] = e_dim

@@ -24,6 +24,7 @@ import math
 import time
 
 from benchmarks import kernel_bench, paper_tables
+from repro.compile_cache import setup_compile_cache
 
 
 #: CI floor for ``replay_events_per_sec`` on the (reduced-size) large tier.
@@ -428,6 +429,7 @@ def main() -> None:
                          "differential included); write it with --json-out")
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.smoke or args.artifact:
         results = smoke() if args.smoke else bench_artifact(scale="full")

@@ -73,7 +73,7 @@ from .engine import (
 from .ledger import CostLedger, CostReport
 from .metadata import COMMITTED, MetadataServer
 from .oracle import TraceOracle
-from .policies import make_policy
+from .policies import Policy, make_policy
 from .routing import VEC_ROUTE_MIN
 from .simulator import Simulator
 from .traces import Trace
@@ -147,6 +147,10 @@ class DiffReport:
     #: byte-identical (the same emit-when-present pattern as
     #: ``availability``).
     latency: Optional[Dict] = None
+    #: The two plane runs that were diffed ({"sim": ..., "live": ...}), for
+    #: callers that inspect a plane further; never serialized.
+    planes: Dict[str, PlaneRun] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @property
     def n_placement_divergence(self) -> int:
@@ -237,6 +241,11 @@ class PlaneRun:
     #: (epoch_idx, t, {bucket: replica set}) per epoch-solver run
     #: (empty unless the policy defines ``epoch``, i.e. SPANStore).
     epoch_sets: List[Tuple[int, float, Dict[str, Tuple[str, ...]]]]
+    #: The policy instance that drove the plane, in its end-of-run state
+    #: (e.g. SkyStore's TTL controller: resolved engine, refresh count).
+    policy: Policy
+    #: Wall-clock seconds of the replay loop itself (set-up excluded).
+    seconds: float
 
 
 def run_sim_plane(
@@ -248,9 +257,11 @@ def run_sim_plane(
     sim = Simulator(cost, policy, mode=mode, scan_interval=scan_interval,
                     track_decisions=True, outages=outages, routing=routing,
                     track_latency=track_latency)
+    t0 = time.perf_counter()
     report = sim.run(trace)
+    dt = time.perf_counter() - t0
     return PlaneRun(report, sim.decisions, sim.replica_holders(),
-                    sim.epoch_sets)
+                    sim.epoch_sets, policy, dt)
 
 
 class _ReplayBackend(InMemoryBackend):
@@ -442,10 +453,13 @@ def run_live_plane(
         track_latency=track_latency, **policy_kw)
     if outages is None:
         outages = trace.outages
+    t0 = time.perf_counter()
     decisions, epoch_sets = _drive_live_spine(store, policy, trace,
                                               scan_interval, horizon, outages)
+    dt = time.perf_counter() - t0
     report = ledger.finalize(horizon, store.meta)
-    return PlaneRun(report, decisions, _live_holders(store.meta), epoch_sets)
+    return PlaneRun(report, decisions, _live_holders(store.meta), epoch_sets,
+                    policy, dt)
 
 
 def live_replay_throughput(
@@ -613,6 +627,7 @@ def replay_differential(
         availability=(sim_rep.availability() if outages is not None
                       and len(outages) else None),
         latency=latency,
+        planes={"sim": sim, "live": live},
     )
 
 

@@ -22,7 +22,7 @@ The region axis is ``sorted(cost.region_names())``.  The scalar tie-break is
 ``min(holders, key=lambda h: (egress_price(h, dst), h))`` -- price first,
 then region *name*.  Because the axis is name-sorted, ``np.argmin``'s
 first-minimum-index plateau discipline (the same convention
-``repro.kernels.ops._canonical_argmin`` pins for the TTL surface) lands on
+``repro.kernels.ops.canonical_argmin`` pins for the TTL surface) lands on
 exactly the lexicographically-smallest cheapest region.  No tolerance band is
 needed here: both paths read the *same* float from the *same* price table,
 so equal prices are bit-equal, never merely close.

@@ -164,6 +164,9 @@ def render_delete_result(deleted, errors) -> bytes:
 
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
+    # Headers and body leave in separate writes; with Nagle on, the body
+    # waits for the client's delayed ACK (~40 ms) on every kept-alive reply.
+    disable_nagle_algorithm = True
     store: ObjectStoreAPI = None    # injected by S3Proxy
     region: str = None
 

@@ -199,7 +199,7 @@ class EdgeTTL:
 
 
 #: TTL-selection engines the refresh loop can run on (see
-#: :meth:`AdaptiveTTLController._resolve_engine`):
+#: :meth:`AdaptiveTTLController.resolve_engine`):
 #:
 #:   numpy   batched float64 :func:`batched_cost_curves` -- bit-identical to
 #:           the per-edge scalar path, the off-TPU production default;
@@ -258,6 +258,9 @@ class AdaptiveTTLController:
             raise ValueError(f"unknown TTL engine {engine!r}; have {TTL_ENGINES}")
         self.engine = engine
         self._engine_resolved: Optional[str] = None
+        #: Refreshes that solved edge TTLs (past warmup), all on the one
+        #: engine :meth:`resolve_engine` pins.
+        self.n_refreshes = 0
 
     # -- statistics ingestion ------------------------------------------------
     def hist_for(self, bucket: str, region: str) -> RollingHistogram:
@@ -338,11 +341,11 @@ class AdaptiveTTLController:
             if src != dst
         ]
         if not ttls:
-            return self.cost.t_even_seconds(dst, dst) if False else np.inf
+            return np.inf
         return float(min(ttls))
 
     # -- refresh loop ----------------------------------------------------------
-    def _resolve_engine(self) -> str:
+    def resolve_engine(self) -> str:
         """Pin the ``auto`` engine choice once per controller: the Pallas
         kernel on TPU hosts, the batched float64 numpy path everywhere else
         (per-refresh jit dispatch overhead dwarfs the arithmetic at replay
@@ -351,12 +354,8 @@ class AdaptiveTTLController:
         if self._engine_resolved is None:
             eng = self.engine
             if eng == "auto":
-                try:
-                    import jax
-                    eng = ("kernel" if jax.default_backend() == "tpu"
-                           else "numpy")
-                except Exception:
-                    eng = "numpy"
+                import jax
+                eng = "kernel" if jax.default_backend() == "tpu" else "numpy"
             self._engine_resolved = eng
         return self._engine_resolved
 
@@ -372,7 +371,8 @@ class AdaptiveTTLController:
             return
         s = self.cost.storage_price(dst)
         srcs = [src for src in self.cost.region_names() if src != dst]
-        engine = self._resolve_engine()
+        engine = self.resolve_engine()
+        self.n_refreshes += 1
         if self.u_perf_val_per_gb > 0 or engine == "python":
             # Scalar reference path: the §3.3.2 perf-value lift walks the
             # per-edge curve beyond the argmin, so it stays on the scalar

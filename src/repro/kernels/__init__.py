@@ -4,7 +4,7 @@
                       control-plane hot spot; VPU prefix-sum workload)
   flash_attention  -- streaming fused attention for prefill/serving (MXU)
   ref              -- pure-jnp oracles for both
-  ops              -- jitted wrappers (interpret=True off-TPU)
+  ops              -- wrappers (compiled; interpret=True only when asked)
 """
 
 from .ops import flash_attention, rwkv6_scan, ttl_scan, ttl_scan_from_histograms  # noqa: F401

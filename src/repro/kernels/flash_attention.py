@@ -33,9 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_KV = 128
 NEG_INF = -1e30
@@ -148,7 +145,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d_p), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

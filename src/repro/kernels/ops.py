@@ -1,8 +1,9 @@
-"""Jitted public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
 Each op accepts natural shapes/dtypes, handles padding + layout, calls the
-kernel (``interpret=True`` on CPU so the whole framework runs end-to-end off-
-TPU), and exposes the pure-jnp oracle fallback via ``use_kernel=False``.
+compiled kernel, and exposes the pure-jnp oracle via ``use_kernel=False``.
+Off-TPU the kernel runs only where the caller asks for the Pallas
+interpreter with ``interpret=True`` (the CPU tests do).
 """
 
 from __future__ import annotations
@@ -18,10 +19,6 @@ from .flash_attention import flash_attention_bhsd
 from .ttl_scan import ttl_cost_surface
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # TTL expected-cost scan
 # ---------------------------------------------------------------------------
@@ -29,7 +26,7 @@ def _on_tpu() -> bool:
 def ttl_scan(
     hist, time_w, last, edges, s_price, n_price, first_remote,
     use_kernel: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Batched TTL selection over E directed edges.
 
@@ -44,10 +41,9 @@ def ttl_scan(
     first_remote = jnp.asarray(first_remote, jnp.float32)
 
     if use_kernel:
-        interp = (not _on_tpu()) if interpret is None else interpret
         surface = ttl_cost_surface(
             hist, time_w, last, edges, s_price, n_price, first_remote,
-            interpret=interp,
+            interpret=interpret,
         )
     else:
         surface = ref.ttl_cost_ref(
@@ -62,33 +58,54 @@ def ttl_scan(
     return ttls[idx], jnp.take_along_axis(full, idx[:, None], 1)[:, 0], full
 
 
-#: Relative band for canonicalizing float32 argmin ties.  Exact cost-tie
-#: plateaus exist in real surfaces (zero misses and zero censored tail beyond
-#: a cell make consecutive candidates *exactly* equal in float64); float32
-#: rounding wobble can then move a plain argmin off the plateau start.  Any
-#: band in 2**-22 .. 2**-18 recovers the float64 plateau-start index on the
-#: full replay-harvested corpus (see tests/test_kernel_plane_equivalence.py);
-#: 2**-20 sits in the middle of that plateau of valid bands.
-TIE_BAND = 2.0 ** -20
+#: Float32 candidates closer than this (relative) to a row's minimum are
+#: resolved in float64.  Every term of the cost surface is a sum of
+#: non-negative values, so either float32 engine sits within about 1e-6 of
+#: the float64 cost on every candidate; candidates closer than twice that
+#: cannot be ordered in float32, and real replay histograms hold such near
+#: ties.  2**-16 leaves an order of magnitude of margin.
+REFINE_BAND = 2.0 ** -16
 
 
-def _canonical_argmin(surface: np.ndarray) -> np.ndarray:
-    """First index within ``TIE_BAND`` of each row minimum.
+def canonical_argmin(surface: np.ndarray, hist: np.ndarray,
+                     last: np.ndarray, exact) -> np.ndarray:
+    """The float64 argmin of each row, decided from a float32 surface.
 
-    This is the decision rule both float32 engines share so that the chosen
-    *index* -- and therefore the float64 candidate TTL it maps to -- matches
-    the pure-float64 ``choose_ttl`` argmin even on exact-tie plateaus.
+    This is the decision rule both float32 engines share, so that the chosen
+    *index* -- and therefore the float64 candidate TTL it maps to -- is the
+    one the pure-float64 ``choose_ttl`` argmin picks.  Two steps:
+
+    * Plateau.  Past the last cell holding re-read (``hist``) or paused
+      (``last``) bytes nothing misses and nothing is paused, so in float64
+      every later candidate costs exactly what the plateau start costs and
+      the argmin never lies beyond it.  The float32 scan sums the same bytes
+      in a different grouping on every lane, so its plateau wobbles by a few
+      ulps; the surface is cut at the plateau start.
+    * Near ties.  A row whose cut surface has more than one candidate within
+      :data:`REFINE_BAND` of its minimum takes the argmin of ``exact(rows)``,
+      the float64 surfaces of those rows.  Other rows take the float32
+      argmin, which the band proves is the float64 one.
     """
     surf = np.asarray(surface, dtype=np.float64)
+    held = (np.asarray(hist) > 0) | (np.asarray(last) > 0)        # [E, C]
+    c_dim = held.shape[1]
+    last_cell = np.where(held.any(axis=1),
+                         c_dim - 1 - np.argmax(held[:, ::-1], axis=1), -1)
+    beyond = np.arange(surf.shape[1])[None, :] > last_cell[:, None] + 1
+    surf = np.where(beyond, np.inf, surf)
     mn = surf.min(axis=1, keepdims=True)
-    return np.argmax(surf <= mn * (1.0 + TIE_BAND), axis=1)
+    idx = np.argmin(surf, axis=1)
+    rows = np.flatnonzero((surf <= mn * (1.0 + REFINE_BAND)).sum(axis=1) > 1)
+    if rows.size:
+        idx[rows] = np.argmin(exact(rows), axis=1)
+    return idx
 
 
 def ttl_scan_from_histograms(
     histograms, cost_model, targets,
     use_kernel: bool = True,
     engine: str | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ):
     """Batched TTL selection for problems built from
     :class:`repro.core.histogram.AccessHistogram` objects.
@@ -108,6 +125,7 @@ def ttl_scan_from_histograms(
     every row against the wrong cell boundaries.
     """
     from repro.core.costmodel import GB, SECONDS_PER_MONTH
+    from repro.core.ttl_policy import batched_cost_curves
 
     if engine is None:
         engine = "kernel" if use_kernel else "jax"
@@ -133,7 +151,15 @@ def ttl_scan_from_histograms(
         use_kernel=(engine == "kernel"), interpret=interpret,
     )
     surface = np.asarray(surface, dtype=np.float64)
-    idx = _canonical_argmin(surface)
+
+    def exact(rows):
+        # Near-tie rows are resolved, and reported, in float64.
+        _, cost = batched_cost_curves(hist[rows], time_w[rows], last[rows],
+                                      edges, first[rows], s[rows], n[rows])
+        surface[rows] = cost
+        return cost
+
+    idx = canonical_argmin(surface, hist, last, exact)
     candidates = np.concatenate([[0.0], np.asarray(edges, dtype=np.float64)])
     return candidates[idx], surface[np.arange(idx.shape[0]), idx], surface
 
@@ -151,7 +177,7 @@ def flash_attention(
     block_q: int = 128,
     block_kv: int = 128,
     use_kernel: bool = True,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """GQA-aware fused attention: repeats kv heads to q heads, folds (B, H)
     into the kernel batch, unpads on the way out."""
@@ -162,7 +188,6 @@ def flash_attention(
         v_ = jnp.repeat(v, hq // hkv, axis=1)
         return ref.mha_ref(q, k_, v_, causal=causal, q_offset=q_offset)
 
-    interp = (not _on_tpu()) if interpret is None else interpret
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv
@@ -172,7 +197,7 @@ def flash_attention(
     out = flash_attention_bhsd(
         fold(q), fold(k), fold(v),
         causal=causal, q_offset=q_offset,
-        block_q=block_q, block_kv=block_kv, interpret=interp,
+        block_q=block_q, block_kv=block_kv, interpret=interpret,
     )
     return out.reshape(b, hq, sq, d)
 

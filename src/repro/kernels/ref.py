@@ -15,6 +15,12 @@ import jax.numpy as jnp
 # TTL expected-cost scan (paper §3.2.2) -- oracle
 # ---------------------------------------------------------------------------
 
+def _exclusive_suffix_sum(x: jax.Array) -> jax.Array:
+    """``out[:, j] = sum(x[:, j+1:])``."""
+    tail = jnp.cumsum(x[:, :0:-1], axis=1)[:, ::-1]
+    return jnp.concatenate([tail, jnp.zeros_like(x[:, :1])], axis=1)
+
+
 def ttl_cost_ref(
     hist: jax.Array,        # [E, C] bytes re-read per cell (float32, GB units ok)
     time_w: jax.Array,      # [E, C] sum(gap * bytes) per cell
@@ -35,16 +41,12 @@ def ttl_cost_ref(
     lower = jnp.concatenate([jnp.zeros_like(edges[:1]), edges[:-1]])
     mid = (0.5 * (lower + edges))[None, :]
 
-    t_hat = jnp.where(hist > 0, time_w / jnp.maximum(hist, 1e-30), mid)
-    hit_csum = jnp.cumsum(hist * t_hat, axis=1)
-    hist_csum = jnp.cumsum(hist, axis=1)
-    last_csum = jnp.cumsum(last, axis=1)
+    hit_csum = jnp.cumsum(jnp.where(hist > 0, time_w, 0.0), axis=1)
     age_csum = jnp.cumsum(last * mid, axis=1)
-    total_hist = hist_csum[:, -1:]
-    total_last = last_csum[:, -1:]
-
-    miss = total_hist - hist_csum
-    tail = total_last - last_csum
+    # Suffix sums summed directly, not as total - prefix: the difference
+    # cancels in float32 on the long-TTL candidates (see ttl_scan).
+    miss = _exclusive_suffix_sum(hist)
+    tail = _exclusive_suffix_sum(last)
     return (
         first_remote[:, None] * n
         + s * hit_csum

@@ -8,9 +8,10 @@ a cumulative-sum structure over cells -- a natural VPU (8x128 vector unit)
 workload with zero MXU involvement.
 
 TPU adaptation (DESIGN.md §5): we lay the histograms out as (edges x cells)
-tiles. Each grid step loads a (BLOCK_E, C_PAD) tile of the four per-cell arrays
-into VMEM, computes four running sums along the cell axis in fp32, forms the
-four cost terms, and writes the (BLOCK_E, C_PAD) cost surface back to HBM.
+tiles. Each grid step loads a (BLOCK_E, C_PAD) tile of the per-cell arrays
+into VMEM, computes two prefix and two suffix sums along the cell axis in
+fp32, forms the cost terms, and writes the (BLOCK_E, C_PAD) cost surface back
+to HBM.
 C_PAD rounds 800 up to the next multiple of 128 lanes -- 896 (7 x 128); block
 height defaults to 256 sublanes, so the working set is
 
@@ -33,9 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BLOCK_E = 256          # edge rows per grid step (sublane axis)
 LANES = 128
 
@@ -53,6 +51,25 @@ def _inclusive_scan(x: jax.Array) -> jax.Array:
     return x
 
 
+def _exclusive_suffix_scan(x: jax.Array) -> jax.Array:
+    """``out[..., j] = sum(x[..., j+1:])`` along the last axis: the same
+    shift-add rounds as :func:`_inclusive_scan`, run toward lower lanes.
+
+    The miss and tail terms need these suffix sums.  Forming them as
+    ``total - prefix`` cancels in float32 once the suffix is small against
+    the total -- the late, long-TTL candidates -- and moved replay decisions
+    off the float64 argmin; summing the suffix directly keeps its error
+    relative to its own size."""
+    n = x.shape[-1]
+    pad = [(0, 0)] * (x.ndim - 1)
+    x = jnp.pad(x, pad + [(0, 1)])[..., 1:]
+    shift = 1
+    while shift < n:
+        x = x + jnp.pad(x, pad + [(0, shift)])[..., shift:]
+        shift *= 2
+    return x
+
+
 def _ttl_scan_kernel(
     hist_ref, time_w_ref, last_ref, edges_ref, mid_ref,
     s_ref, n_ref, first_ref, cost_ref,
@@ -66,16 +83,12 @@ def _ttl_scan_kernel(
     n = n_ref[...].astype(jnp.float32)                # [BE, 1]
     first = first_ref[...].astype(jnp.float32)        # [BE, 1]
 
-    t_hat = jnp.where(hist > 0, time_w / jnp.maximum(hist, 1e-30), mid)
-    hit_csum = _inclusive_scan(hist * t_hat)
-    hist_csum = _inclusive_scan(hist)
-    last_csum = _inclusive_scan(last)
+    # hist * t_hat with t_hat = time_w / hist is time_w wherever a cell holds
+    # re-reads (0 elsewhere); taking it directly skips a float32 divide.
+    hit_csum = _inclusive_scan(jnp.where(hist > 0, time_w, 0.0))
     age_csum = _inclusive_scan(last * mid)
-
-    total_hist = hist_csum[:, -1:]
-    total_last = last_csum[:, -1:]
-    miss = total_hist - hist_csum
-    tail = total_last - last_csum
+    miss = _exclusive_suffix_scan(hist)
+    tail = _exclusive_suffix_scan(last)
 
     cost_ref[...] = (
         first * n
@@ -126,7 +139,7 @@ def ttl_cost_surface(
         in_specs=[row, row, row, brd, brd, vec, vec, vec],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((e_pad, c_pad), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -17,7 +17,8 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_host_mesh(model: int = 1):
@@ -25,7 +26,8 @@ def make_host_mesh(model: int = 1):
     used by smoke tests and the CPU examples."""
     n = jax.device_count()
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 #: TPU v5e hardware constants used by the roofline (per chip).

@@ -31,7 +31,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train.trainer import TrainState
 
     cfg = get_config("llama3.2-1b").reduced()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = base_rules()
 
     ocfg, opt = make_optimizer("adamw")

@@ -15,6 +15,8 @@ just synthetic random problems:
   surfaces where TTL=0 (evict immediately) wins exactly.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -24,8 +26,17 @@ from repro.core.simulator import Simulator
 from repro.core.policies import make_policy
 from repro.core.ttl_policy import AdaptiveTTLController, TTL_ENGINES
 from repro.core.workloads import make_workload
+from repro.kernels import ops
 
 BATCHED_ENGINES = ("kernel", "jax", "numpy")
+
+
+@pytest.fixture(autouse=True)
+def interpret_kernel(monkeypatch):
+    """Off-TPU the controller's ``engine="kernel"`` refreshes run the Pallas
+    kernel under its interpreter; the program itself never picks that."""
+    monkeypatch.setattr(ops, "ttl_scan_from_histograms", functools.partial(
+        ops.ttl_scan_from_histograms, interpret=True))
 
 
 @pytest.fixture(scope="module")
@@ -97,7 +108,7 @@ def test_auto_engine_resolves_to_batched_member():
     cat = pick_regions(3)
     ctl = AdaptiveTTLController(cat)
     assert ctl.engine == "auto"
-    assert ctl._resolve_engine() in BATCHED_ENGINES
+    assert ctl.resolve_engine() in BATCHED_ENGINES
     assert set(BATCHED_ENGINES) < set(TTL_ENGINES)
 
 
@@ -159,3 +170,38 @@ def test_ttl_zero_wins_exactly(engine):
             h.first_read_remote_bytes, 1)
         ttl = ctl.edge_ttl("b", src, dst, now=1.0)
         assert ttl == 0.0, f"engine {eng} chose {ttl!r}, want exactly 0.0"
+
+
+def test_canonical_argmin_cuts_the_float32_plateau():
+    """Past the last cell holding bytes every candidate costs exactly the
+    plateau start in float64; float32 wobble there must not move the
+    decision off the plateau start, however wide it is."""
+    hist = np.zeros((1, 6))
+    last = np.zeros((1, 6))
+    hist[0, 1] = 5.0
+    last[0, 2] = 1.0              # last held cell 2 -> plateau starts at 3
+    wobble = 5.0 * (1.0 - 1e-5)
+    surface = np.array([[9.0, 8.0, 7.0, 5.0, wobble, 5.0, wobble]])
+
+    def exact(rows):
+        raise AssertionError(f"no near tie to resolve, got rows {rows}")
+
+    assert list(ops.canonical_argmin(surface, hist, last, exact)) == [3]
+
+
+def test_canonical_argmin_resolves_near_ties_in_float64():
+    """Candidates float32 cannot order (within REFINE_BAND of the minimum)
+    are decided by the float64 surfaces of those rows only."""
+    hist = np.ones((2, 4))
+    last = np.zeros((2, 4))
+    surface = np.array([[3.0, 1.0, 1.0 + 1e-7, 2.0, 2.5],
+                        [3.0, 1.0, 1.5, 2.0, 2.5]])
+    float64 = np.array([[3.0, 1.0 + 2e-8, 1.0, 2.0, 2.5]])
+    calls = []
+
+    def exact(rows):
+        calls.append(list(rows))
+        return float64
+
+    assert list(ops.canonical_argmin(surface, hist, last, exact)) == [2, 1]
+    assert calls == [[0]]

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 from repro.core.histogram import cell_edges
 from repro.kernels import flash_attention, ttl_scan
 from repro.kernels import ref
-from repro.kernels.ttl_scan import _inclusive_scan, ttl_cost_surface
+from repro.kernels.ttl_scan import (_exclusive_suffix_scan, _inclusive_scan,
+                                    ttl_cost_surface)
 
 
 def _hist_problem(e_dim, c_dim, seed):
@@ -31,7 +32,7 @@ def _hist_problem(e_dim, c_dim, seed):
                                          (64, 800), (5, 123), (2, 1024)])
 def test_ttl_scan_kernel_vs_oracle(e_dim, c_dim):
     prob = _hist_problem(e_dim, c_dim, seed=e_dim * 1000 + c_dim)
-    _, _, full_k = ttl_scan(*prob, use_kernel=True)
+    _, _, full_k = ttl_scan(*prob, use_kernel=True, interpret=True)
     _, _, full_r = ttl_scan(*prob, use_kernel=False)
     np.testing.assert_allclose(np.asarray(full_k), np.asarray(full_r),
                                rtol=2e-5, atol=1e-4)
@@ -71,7 +72,7 @@ def test_ttl_scan_matches_core_policy_math():
     best_ttl, best_cost, full = ttl_scan(
         h.hist[None], h.time_weight[None], h.last[None], h.edges,
         np.asarray([s]), np.asarray([n]),
-        np.asarray([h.first_read_remote_bytes]))
+        np.asarray([h.first_read_remote_bytes]), interpret=True)
     np.testing.assert_allclose(np.asarray(full[0]), cost, rtol=2e-4)
     assert float(best_ttl[0]) == pytest.approx(
         float(ttls[np.argmin(cost)]), rel=0.03)
@@ -95,7 +96,8 @@ def test_flash_attention_vs_oracle(b, hq, hkv, sq, skv, d, causal, off, dtype):
                           jnp.float32).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, hkv, skv, d),
                           jnp.float32).astype(dtype)
-    out_k = flash_attention(q, k, v, causal=causal, q_offset=off)
+    out_k = flash_attention(q, k, v, causal=causal, q_offset=off,
+                            interpret=True)
     out_r = flash_attention(q, k, v, causal=causal, q_offset=off,
                             use_kernel=False)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -111,7 +113,8 @@ def test_flash_attention_block_sweep():
     v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, 384, 64))
     base = flash_attention(q, k, v, use_kernel=False)
     for bq, bkv in [(128, 128), (128, 256), (256, 128)]:
-        out = flash_attention(q, k, v, block_q=bq, block_kv=bkv)
+        out = flash_attention(q, k, v, block_q=bq, block_kv=bkv,
+                              interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    rtol=3e-5, atol=3e-5)
 
@@ -148,6 +151,42 @@ def test_inclusive_scan_any_length(n):
     np.testing.assert_allclose(np.asarray(out),
                                np.cumsum(x.astype(np.float64), axis=1),
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 7, 100, 123, 800, 896, 1024])
+def test_exclusive_suffix_scan_any_length(n):
+    """out[j] = sum(x[j+1:]) at awkward lengths, like the prefix scan."""
+    rng = np.random.default_rng(n + 1)
+    x = rng.uniform(0.1, 2.0, size=(3, n)).astype(np.float32)
+    out = _exclusive_suffix_scan(jnp.asarray(x))
+    want = np.cumsum(x.astype(np.float64)[:, ::-1], axis=1)[:, ::-1] - x
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-6)
+
+
+def test_ttl_scan_long_ttl_candidates_match_float64():
+    """A heavy head and a light tail of re-read and paused bytes: on the
+    long-TTL candidates the bytes still missing or paused are tiny against
+    the totals.  Taken as total - prefix in float32 they cancel to noise;
+    the kernel must stay within float32 rounding of the float64 surface on
+    every candidate."""
+    from repro.core.ttl_policy import batched_cost_curves
+
+    edges = cell_edges()
+    c_dim = edges.shape[0]
+    hist = np.zeros((1, c_dim), np.float32)
+    last = np.zeros((1, c_dim), np.float32)
+    hist[0, 0] = last[0, 0] = 1e12
+    hist[0, -40::8] = last[0, -30::6] = 1e3
+    time_w = (hist * edges[None].astype(np.float32) * 0.9).astype(np.float32)
+    s = np.asarray([1e-17], np.float32)
+    n = np.asarray([2e-11], np.float32)
+    first = np.asarray([1e9], np.float32)
+    surface = ttl_cost_surface(
+        *[jnp.asarray(x, jnp.float32)
+          for x in (hist, time_w, last, edges, s, n, first)],
+        interpret=True)
+    _, cost64 = batched_cost_curves(hist, time_w, last, edges, first, s, n)
+    np.testing.assert_allclose(np.asarray(surface), cost64[:, 1:], rtol=1e-5)
 
 
 @pytest.mark.parametrize("c_dim", [123, 257, 800, 900])
