@@ -1,0 +1,1 @@
+"""SkyStore benchmark: see BENCHMARK.json and PERF.md."""

@@ -1,0 +1,20 @@
+"""TTL selection's share of its roofline: the least time the chip needs for
+the refreshes solved in the window (``bench/device/ttl_work.py``, from each
+problem's real shape), over the device time of every program that ran
+inside the TTL host spans."""
+
+from bench.device import peaks, ttl_work
+
+
+def read(run):
+    n = run.counters.get("ttl_refreshes")
+    if run.device is None or not n:
+        return None
+    device_s = run.device.seconds_inside("bench.ttl")
+    if device_s <= 0:
+        return None
+    edges = len(run.config["regions"]) - 1
+    h = run.config["ttl"]["histogram"]
+    cells = h["linear_cells"] + h["log_cells"]
+    least = ttl_work.least_seconds(n, edges, cells, peaks.peaks(run.device_kind))
+    return 100.0 * least / device_s
