@@ -77,6 +77,7 @@ from .policies import Policy, make_policy
 from .routing import VEC_ROUTE_MIN
 from .simulator import Simulator
 from .traces import Trace
+from . import tracing
 from .virtual_store import VirtualStore
 from .workloads import make_outage_schedule, make_workload
 
@@ -253,15 +254,27 @@ def run_sim_plane(
     scan_interval: float = DAY, outages: Optional[OutageSchedule] = None,
     routing: str = "auto", track_latency: bool = False, **policy_kw,
 ) -> PlaneRun:
-    policy = make_policy(policy_name, cost, **policy_kw)
-    sim = Simulator(cost, policy, mode=mode, scan_interval=scan_interval,
-                    track_decisions=True, outages=outages, routing=routing,
-                    track_latency=track_latency)
-    t0 = time.perf_counter()
-    report = sim.run(trace)
-    dt = time.perf_counter() - t0
-    return PlaneRun(report, sim.decisions, sim.replica_holders(),
-                    sim.epoch_sets, policy, dt)
+    with tracing.span("skystore.replay.run"):
+        policy = make_policy(policy_name, cost, **policy_kw)
+        sim = Simulator(cost, policy, mode=mode, scan_interval=scan_interval,
+                        track_decisions=True, outages=outages,
+                        routing=routing, track_latency=track_latency)
+        t0 = time.perf_counter()
+        report = sim.run(trace)
+        dt = time.perf_counter() - t0
+        run = PlaneRun(report, sim.decisions, sim.replica_holders(),
+                       sim.epoch_sets, policy, dt)
+    _publish_counters(sim, sim.expiry)
+    return run
+
+
+def _publish_counters(plane, expiry) -> None:
+    """One replay's routing and expiry counters, into a running recording
+    (:mod:`repro.core.tracing`)."""
+    tracing.count("routing.get_hinted", plane.n_get_hinted)
+    tracing.count("routing.get_scalar", plane.n_get_scalar)
+    tracing.count("expiry.pops", expiry.n_pops)
+    tracing.count("expiry.stale", expiry.n_stale)
 
 
 class _ReplayBackend(InMemoryBackend):
@@ -448,18 +461,21 @@ def run_live_plane(
     transitions -- ``outages`` falls back to ``trace.outages``) in the
     identical order by construction.  Pass ``backends`` to inspect physical
     traffic counters afterwards."""
-    store, ledger, policy, horizon = _make_live_plane(
-        trace, cost, policy_name, mode, backends, routing=routing,
-        track_latency=track_latency, **policy_kw)
-    if outages is None:
-        outages = trace.outages
-    t0 = time.perf_counter()
-    decisions, epoch_sets = _drive_live_spine(store, policy, trace,
-                                              scan_interval, horizon, outages)
-    dt = time.perf_counter() - t0
-    report = ledger.finalize(horizon, store.meta)
-    return PlaneRun(report, decisions, _live_holders(store.meta), epoch_sets,
-                    policy, dt)
+    with tracing.span("skystore.replay.run"):
+        store, ledger, policy, horizon = _make_live_plane(
+            trace, cost, policy_name, mode, backends, routing=routing,
+            track_latency=track_latency, **policy_kw)
+        if outages is None:
+            outages = trace.outages
+        t0 = time.perf_counter()
+        decisions, epoch_sets = _drive_live_spine(
+            store, policy, trace, scan_interval, horizon, outages)
+        dt = time.perf_counter() - t0
+        report = ledger.finalize(horizon, store.meta)
+        run = PlaneRun(report, decisions, _live_holders(store.meta),
+                       epoch_sets, policy, dt)
+    _publish_counters(store, store.meta.expiry)
+    return run
 
 
 def live_replay_throughput(
@@ -469,8 +485,8 @@ def live_replay_throughput(
     **policy_kw,
 ) -> Dict[str, float]:
     """Time one live-plane replay; returns events/sec plus the expiry-index
-    counters the benchmark smoke guards on (the events/sec floor is the
-    regression signal against O(objects) per-event work creeping back).
+    pop count (the events/sec floor is the benchmark smoke's regression
+    signal against O(objects) per-event work creeping back).
     ``outages`` (falling back to ``trace.outages``) times the replay under a
     §6.4 failure schedule -- the chaos-overhead benchmark."""
     store, ledger, policy, horizon = _make_live_plane(
@@ -489,7 +505,6 @@ def live_replay_throughput(
         "seconds": dt,
         "events_per_sec": n / dt if dt > 0 else float("inf"),
         "expiry_pops": store.meta.expiry.n_pops,
-        "expiry_stale": store.meta.expiry.n_stale,
         "total_cost": report.total,
     }
 

@@ -32,6 +32,7 @@ Operations (the full §4.3 surface):
 
 from __future__ import annotations
 
+import functools
 import threading
 import xml.etree.ElementTree as ET
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -60,6 +61,7 @@ from .api import (
     UploadPartRequest,
     parse_range_header,
 )
+from . import tracing
 
 # ---------------------------------------------------------------------------
 # XML codec helpers (pure functions: body bytes <-> request/response objects)
@@ -162,6 +164,17 @@ def render_delete_result(deleted, errors) -> bytes:
 # ---------------------------------------------------------------------------
 
 
+def _request_span(verb):
+    """Time one verb handler, from the parsed request line to the written
+    response, as kept span ``skystore.s3.request``."""
+    @functools.wraps(verb)
+    def handle(self):
+        with tracing.span("skystore.s3.request"):
+            verb(self)
+
+    return handle
+
+
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     # Headers and body leave in separate writes; with Nagle on, the body
@@ -219,6 +232,7 @@ class _Handler(BaseHTTPRequestHandler):
         return q[name][0] if name in q else default
 
     # -- verbs ---------------------------------------------------------------
+    @_request_span
     def do_GET(self):
         bucket, key, q = self._split()
         try:
@@ -262,6 +276,7 @@ class _Handler(BaseHTTPRequestHandler):
         except ValueError as e:
             self._error(400, "InvalidArgument", str(e))
 
+    @_request_span
     def do_HEAD(self):
         bucket, key, _q = self._split()
         try:
@@ -282,6 +297,7 @@ class _Handler(BaseHTTPRequestHandler):
         except KeyError as e:
             self._error(404, "NoSuchKey", str(e))
 
+    @_request_span
     def do_PUT(self):
         bucket, key, q = self._split()
         try:
@@ -316,6 +332,7 @@ class _Handler(BaseHTTPRequestHandler):
         except ValueError as e:
             self._error(400, "InvalidArgument", str(e))
 
+    @_request_span
     def do_POST(self):
         bucket, key, q = self._split()
         try:
@@ -350,6 +367,7 @@ class _Handler(BaseHTTPRequestHandler):
         except ValueError as e:
             self._error(400, "InvalidArgument", str(e))
 
+    @_request_span
     def do_DELETE(self):
         bucket, key, q = self._split()
         try:

@@ -160,6 +160,11 @@ class Simulator:
             RoutingMatrix(cost, latency_weight=self.latency_weight)
             if self._routing_engine == "matrix" else None
         )
+        #: GETs routed off a fresh routing hint, and GETs routed by the
+        #: scalar ``choose_get_source`` (no hint, a stale one, a non-OK
+        #: status, or ``routing="python"``).
+        self.n_get_hinted = 0
+        self.n_get_scalar = 0
 
     # -- accounting -------------------------------------------------------------
     def _charge_storage(self, obj: ObjectState, rep: Replica, end: float) -> None:
@@ -455,9 +460,11 @@ class Simulator:
                 if st == ROUTE_OK:
                     src, hit = _hints.srcs[_k], _hints.hits[_k]
                     hinted = True
+                    self.n_get_hinted += 1
                 elif st == ROUTE_UNAVAILABLE:
                     # Every holder is dark: the identical outcome (and
                     # decision tuple) the scalar ApiError branch records.
+                    self.n_get_hinted += 1
                     self.report.n_unavailable += 1
                     if self.track_decisions:
                         self.decisions.append(
@@ -471,6 +478,7 @@ class Simulator:
         # replica table between the two reads, so sharing it is invisible.
         holders = None
         if not hinted:
+            self.n_get_scalar += 1
             try:
                 holders = self.holders(obj)
                 src, hit = choose_get_source(holders, region, now,

@@ -39,6 +39,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .costmodel import GB, SECONDS_PER_MONTH, CostModel
 from .histogram import AccessHistogram, RollingHistogram, cell_edges
 
@@ -365,8 +366,15 @@ class AdaptiveTTLController:
         if now - last < self.refresh_period:
             return
         self.last_refresh[key] = now
+        # One refresh span per period; one that stops at the warm-up gate
+        # holds only its merge, one that solves also a scan.
+        with tracing.span("skystore.ttl.refresh"):
+            self._refresh(bucket, dst, now)
+
+    def _refresh(self, bucket: str, dst: str, now: float) -> None:
         roll = self.hist_for(bucket, dst)
-        merged = roll.merged()
+        with tracing.span("skystore.ttl.merge"):
+            merged = roll.merged()
         if merged.n_samples < self.warmup_min_samples:
             return
         s = self.cost.storage_price(dst)
@@ -378,17 +386,18 @@ class AdaptiveTTLController:
             # per-edge curve beyond the argmin, so it stays on the scalar
             # implementation; engine="python" keeps the legacy loop
             # selectable as the equivalence oracle.
-            for src in srcs:
-                n = self.cost.egress_price(src, dst)
-                if self.u_perf_val_per_gb > 0:
-                    ttl = choose_ttl_with_perf_value(
-                        merged, s, n, self.u_perf_val_per_gb)
-                else:
-                    ttl = choose_ttl(merged, s, n)
-                _ttls_c, cost_c = expected_cost_curve(merged, s, n)
-                self.edge_ttls[(bucket, src, dst)] = EdgeTTL(
-                    ttl, now, float(cost_c.min())
-                )
+            with tracing.span("skystore.ttl.scan"):
+                for src in srcs:
+                    n = self.cost.egress_price(src, dst)
+                    if self.u_perf_val_per_gb > 0:
+                        ttl = choose_ttl_with_perf_value(
+                            merged, s, n, self.u_perf_val_per_gb)
+                    else:
+                        ttl = choose_ttl(merged, s, n)
+                    _ttls_c, cost_c = expected_cost_curve(merged, s, n)
+                    self.edge_ttls[(bucket, src, dst)] = EdgeTTL(
+                        ttl, now, float(cost_c.min())
+                    )
         else:
             ttls, costs = self._refresh_batched(merged, dst, srcs, engine)
             for src, ttl, c in zip(srcs, ttls, costs):
@@ -418,16 +427,20 @@ class AdaptiveTTLController:
         n_gb = [self.cost.egress_price(src, dst) for src in srcs]
         if engine == "numpy":
             e_dim = len(srcs)
-            s = np.asarray([s_gbmo / GB / SECONDS_PER_MONTH] * e_dim)
-            n = np.asarray([x / GB for x in n_gb])
-            hist = np.broadcast_to(merged.hist, (e_dim, merged.hist.shape[0]))
-            time_w = np.broadcast_to(merged.time_weight, hist.shape)
-            last = np.broadcast_to(merged.last, hist.shape)
-            first = np.full(e_dim, merged.first_read_remote_bytes)
-            ttls, cost = batched_cost_curves(
-                hist, time_w, last, merged.edges, first, s, n)
-            idx = np.argmin(cost, axis=1)
-            return ttls[idx], cost[np.arange(e_dim), idx]
+            with tracing.span("skystore.ttl.inputs"):
+                s = np.asarray([s_gbmo / GB / SECONDS_PER_MONTH] * e_dim)
+                n = np.asarray([x / GB for x in n_gb])
+                hist = np.broadcast_to(merged.hist,
+                                       (e_dim, merged.hist.shape[0]))
+                time_w = np.broadcast_to(merged.time_weight, hist.shape)
+                last = np.broadcast_to(merged.last, hist.shape)
+                first = np.full(e_dim, merged.first_read_remote_bytes)
+            with tracing.span("skystore.ttl.scan"):
+                ttls, cost = batched_cost_curves(
+                    hist, time_w, last, merged.edges, first, s, n)
+            with tracing.span("skystore.ttl.resolve"):
+                idx = np.argmin(cost, axis=1)
+                return ttls[idx], cost[np.arange(e_dim), idx]
         # kernel / jax: the float32 batched scan with float64 candidate
         # resolution (repro.kernels.ops canonicalizes argmin ties).
         from repro.kernels.ops import ttl_scan_from_histograms

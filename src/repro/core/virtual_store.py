@@ -224,6 +224,11 @@ class VirtualStore:
         # policy-mode bookkeeping, mirroring Simulator._last_get/_open_last
         self._last_get: Dict[Tuple[str, str, str], float] = {}
         self._open_last: Dict[Tuple[str, str], Dict[object, Tuple[float, float]]] = {}
+        #: GETs served off a fresh routing hint, and GETs routed by the
+        #: scalar ``MetadataServer.locate`` (no hint, a stale one, a non-OK
+        #: status, lost bytes, a versioned read, or ``routing="python"``).
+        self.n_get_hinted = 0
+        self.n_get_scalar = 0
 
     # -- the unified op entry point ------------------------------------------
     def dispatch(self, op: Request):
@@ -413,6 +418,7 @@ class VirtualStore:
                         full = self.backends[src].get(
                             op.bucket, self._pkey(op.key, vm.version))
                     hinted = True
+                    self.n_get_hinted += 1
                 except KeyError:
                     lost = vm.replicas.pop(src, None)    # read-repair (§4.5)
                     if lost is not None:
@@ -422,6 +428,8 @@ class VirtualStore:
                                                     now, version=vm.version)
                     if not vm.replicas:
                         raise
+        if not hinted:
+            self.n_get_scalar += 1
         for _attempt in range(0 if hinted else len(self.backends) + 1):
             try:
                 vm, src, hit = self.meta.locate(op.bucket, op.key, op.region,

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
+
 from . import ref
 from .flash_attention import flash_attention_bhsd
 from .ttl_scan import ttl_cost_surface
@@ -135,22 +137,25 @@ def ttl_scan_from_histograms(
     for h in histograms[1:]:
         if h.edges.shape != edges.shape or not np.allclose(h.edges, edges):
             raise ValueError("histograms with different cell layouts")
-    hist = np.stack([h.hist for h in histograms])
-    time_w = np.stack([h.time_weight for h in histograms])
-    last = np.stack([h.last for h in histograms])
-    first = np.asarray([h.first_read_remote_bytes for h in histograms])
-    s = np.asarray([
-        cost_model.storage_price(dst) / GB / SECONDS_PER_MONTH
-        for (_src, dst) in targets
-    ])
-    n = np.asarray([
-        cost_model.egress_price(src, dst) / GB for (src, dst) in targets
-    ])
-    _ttl32, _cost32, surface = ttl_scan(
-        hist, time_w, last, edges, s, n, first,
-        use_kernel=(engine == "kernel"), interpret=interpret,
-    )
-    surface = np.asarray(surface, dtype=np.float64)
+    with tracing.span("skystore.ttl.inputs"):
+        hist = np.stack([h.hist for h in histograms])
+        time_w = np.stack([h.time_weight for h in histograms])
+        last = np.stack([h.last for h in histograms])
+        first = np.asarray([h.first_read_remote_bytes for h in histograms])
+        s = np.asarray([
+            cost_model.storage_price(dst) / GB / SECONDS_PER_MONTH
+            for (_src, dst) in targets
+        ])
+        n = np.asarray([
+            cost_model.egress_price(src, dst) / GB for (src, dst) in targets
+        ])
+    # Transfers in, the kernel, the eager epilogue and the copy back.
+    with tracing.span("skystore.ttl.scan"):
+        _ttl32, _cost32, surface = ttl_scan(
+            hist, time_w, last, edges, s, n, first,
+            use_kernel=(engine == "kernel"), interpret=interpret,
+        )
+        surface = np.asarray(surface, dtype=np.float64)
 
     def exact(rows):
         # Near-tie rows are resolved, and reported, in float64.
@@ -159,9 +164,12 @@ def ttl_scan_from_histograms(
         surface[rows] = cost
         return cost
 
-    idx = canonical_argmin(surface, hist, last, exact)
-    candidates = np.concatenate([[0.0], np.asarray(edges, dtype=np.float64)])
-    return candidates[idx], surface[np.arange(idx.shape[0]), idx], surface
+    with tracing.span("skystore.ttl.resolve"):
+        idx = canonical_argmin(surface, hist, last, exact)
+        candidates = np.concatenate([[0.0],
+                                     np.asarray(edges, dtype=np.float64)])
+        return (candidates[idx], surface[np.arange(idx.shape[0]), idx],
+                surface)
 
 
 # ---------------------------------------------------------------------------
