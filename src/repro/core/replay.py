@@ -264,17 +264,21 @@ def run_sim_plane(
         dt = time.perf_counter() - t0
         run = PlaneRun(report, sim.decisions, sim.replica_holders(),
                        sim.epoch_sets, policy, dt)
-    _publish_counters(sim, sim.expiry)
+    _publish_counters(sim, sim.expiry, policy)
     return run
 
 
-def _publish_counters(plane, expiry) -> None:
-    """One replay's routing and expiry counters, into a running recording
-    (:mod:`repro.core.tracing`)."""
+def _publish_counters(plane, expiry, policy) -> None:
+    """One replay's routing, expiry and TTL-scan counters, into a running
+    recording (:mod:`repro.core.tracing`)."""
     tracing.count("routing.get_hinted", plane.n_get_hinted)
     tracing.count("routing.get_scalar", plane.n_get_scalar)
     tracing.count("expiry.pops", expiry.n_pops)
     tracing.count("expiry.stale", expiry.n_stale)
+    ctl = getattr(policy, "ctl", None)      # SkyStore's TTL controller
+    if ctl is not None:
+        tracing.count("ttl.device_scans", ctl.n_device_scans)
+        tracing.count("ttl.scan_compiles", ctl.n_scan_compiles)
 
 
 class _ReplayBackend(InMemoryBackend):
@@ -474,7 +478,7 @@ def run_live_plane(
         report = ledger.finalize(horizon, store.meta)
         run = PlaneRun(report, decisions, _live_holders(store.meta),
                        epoch_sets, policy, dt)
-    _publish_counters(store, store.meta.expiry)
+    _publish_counters(store, store.meta.expiry, policy)
     return run
 
 

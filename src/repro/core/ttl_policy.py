@@ -262,6 +262,10 @@ class AdaptiveTTLController:
         #: Refreshes that solved edge TTLs (past warmup), all on the one
         #: engine :meth:`resolve_engine` pins.
         self.n_refreshes = 0
+        #: Of those, the ones the kernel or jax engine solved as one device
+        #: program, and the ones whose call compiled that program.
+        self.n_device_scans = 0
+        self.n_scan_compiles = 0
 
     # -- statistics ingestion ------------------------------------------------
     def hist_for(self, bucket: str, region: str) -> RollingHistogram:
@@ -443,8 +447,11 @@ class AdaptiveTTLController:
                 return ttls[idx], cost[np.arange(e_dim), idx]
         # kernel / jax: the float32 batched scan with float64 candidate
         # resolution (repro.kernels.ops canonicalizes argmin ties).
-        from repro.kernels.ops import ttl_scan_from_histograms
-        ttls, costs, _surface = ttl_scan_from_histograms(
+        from repro.kernels import ops
+        programs = ops.ttl_scan_programs()
+        ttls, costs, _surface = ops.ttl_scan_from_histograms(
             [merged] * len(srcs), self.cost,
             [(src, dst) for src in srcs], engine=engine)
+        self.n_device_scans += 1
+        self.n_scan_compiles += ops.ttl_scan_programs() - programs
         return np.asarray(ttls), np.asarray(costs)

@@ -25,6 +25,61 @@ from .ttl_scan import ttl_cost_surface
 # TTL expected-cost scan
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit,
+                   static_argnames=("n_rows", "use_kernel", "interpret"))
+def ttl_refresh_surface(packed: jax.Array, edges: jax.Array, n_rows: int,
+                        use_kernel: bool = True, interpret: bool = False):
+    """One refresh as one device program: the ``[E, C+1]`` float32 surface.
+
+    ``packed`` is the float32 buffer :func:`pack_ttl_inputs` builds: ``R``
+    rows of (re-read bytes, gap-weighted bytes, paused bytes) over the ``C``
+    cells of ``edges``, then the ``E`` first-read bytes, storage prices and
+    egress prices.  ``R`` (``n_rows``) is ``E``, or 1 when every edge
+    shares one histogram; the rows are then broadcast here, on the device.
+    Column 0 is candidate TTL=0 (evict at once: every re-read pays N, no
+    storage), column j+1 candidate TTL=edges[j]."""
+    c_dim = edges.shape[0]
+    split = n_rows * 3 * c_dim
+    e_dim = (packed.shape[0] - split) // 3
+    rows = jnp.broadcast_to(packed[:split].reshape(n_rows, 3, c_dim),
+                            (e_dim, 3, c_dim))
+    hist, time_w, last = rows[:, 0], rows[:, 1], rows[:, 2]
+    first, s_price, n_price = packed[split:].reshape(3, e_dim)
+    if use_kernel:
+        surface = ttl_cost_surface(hist, time_w, last, edges, s_price,
+                                   n_price, first, interpret=interpret)
+    else:
+        surface = ref.ttl_cost_ref(hist, time_w, last, edges, s_price,
+                                   n_price, first)
+    zero = (first + hist.sum(axis=1)) * n_price
+    return jnp.concatenate([zero[:, None], surface], axis=1)
+
+
+def ttl_scan_programs() -> int:
+    """How many programs :func:`ttl_refresh_surface` has compiled in this
+    process: one per (E, C, rows, engine)."""
+    return ttl_refresh_surface._cache_size()
+
+
+def device_edges(edges) -> jax.Array:
+    """``edges`` as a float32 device array, transferred once per layout."""
+    edges = np.asarray(edges)
+    return _device_edges(edges.tobytes(), edges.dtype.str)
+
+
+@functools.lru_cache(maxsize=16)
+def _device_edges(raw: bytes, dtype: str) -> jax.Array:
+    return jax.device_put(np.frombuffer(raw, dtype).astype(np.float32))
+
+
+def pack_ttl_inputs(rows, first_remote, s_price, n_price) -> np.ndarray:
+    """``rows`` ``[R, 3, C]`` and the three ``[E]`` per-edge values as the
+    one float32 host buffer :func:`ttl_refresh_surface` reads."""
+    return np.concatenate(
+        [np.ravel(rows), np.ravel(first_remote), np.ravel(s_price),
+         np.ravel(n_price)], dtype=np.float32, casting="same_kind")
+
+
 def ttl_scan(
     hist, time_w, last, edges, s_price, n_price, first_remote,
     use_kernel: bool = True,
@@ -34,27 +89,15 @@ def ttl_scan(
 
     Returns ``(best_ttl [E], best_cost [E], cost_surface [E, C+1])`` where
     candidate 0 is TTL=0 (evict immediately) and candidate j+1 is
-    TTL=edges[j].  All inputs may be numpy or jax arrays.
+    TTL=edges[j].  All inputs may be numpy or jax arrays.  The surface is
+    :func:`ttl_refresh_surface`'s; the float32 argmin is taken on it
+    afterwards (the refresh loop decides with :func:`canonical_argmin`).
     """
-    hist, time_w, last = (jnp.asarray(x, jnp.float32) for x in (hist, time_w, last))
-    edges = jnp.asarray(edges, jnp.float32)
-    s_price = jnp.asarray(s_price, jnp.float32)
-    n_price = jnp.asarray(n_price, jnp.float32)
-    first_remote = jnp.asarray(first_remote, jnp.float32)
-
-    if use_kernel:
-        surface = ttl_cost_surface(
-            hist, time_w, last, edges, s_price, n_price, first_remote,
-            interpret=interpret,
-        )
-    else:
-        surface = ref.ttl_cost_ref(
-            hist, time_w, last, edges, s_price, n_price, first_remote
-        )
-
-    # Candidate TTL=0: every re-read pays N; no storage at all.
-    zero = (first_remote + hist.sum(axis=1)) * n_price
-    full = jnp.concatenate([zero[:, None], surface], axis=1)
+    edges = device_edges(edges)
+    rows = np.stack([np.asarray(x) for x in (hist, time_w, last)], axis=1)
+    full = ttl_refresh_surface(
+        pack_ttl_inputs(rows, first_remote, s_price, n_price), edges,
+        n_rows=rows.shape[0], use_kernel=use_kernel, interpret=interpret)
     idx = jnp.argmin(full, axis=1)
     ttls = jnp.concatenate([jnp.zeros_like(edges[:1]), edges])
     return ttls[idx], jnp.take_along_axis(full, idx[:, None], 1)[:, 0], full
@@ -134,13 +177,18 @@ def ttl_scan_from_histograms(
     if engine not in ("kernel", "jax"):
         raise ValueError(f"unknown ttl_scan engine {engine!r}")
     edges = histograms[0].edges
+    # The refresh loop passes one merged histogram for every edge: its rows
+    # then go to the device once and are broadcast there.
+    shared = all(h is histograms[0] for h in histograms)
     for h in histograms[1:]:
-        if h.edges.shape != edges.shape or not np.allclose(h.edges, edges):
+        if h.edges is not edges and (h.edges.shape != edges.shape
+                                     or not np.allclose(h.edges, edges)):
             raise ValueError("histograms with different cell layouts")
     with tracing.span("skystore.ttl.inputs"):
-        hist = np.stack([h.hist for h in histograms])
-        time_w = np.stack([h.time_weight for h in histograms])
-        last = np.stack([h.last for h in histograms])
+        rows = np.stack([(h.hist, h.time_weight, h.last)
+                         for h in histograms[:1 if shared else None]])
+        hist, time_w, last = np.broadcast_to(
+            rows, (len(histograms),) + rows.shape[1:]).transpose(1, 0, 2)
         first = np.asarray([h.first_read_remote_bytes for h in histograms])
         s = np.asarray([
             cost_model.storage_price(dst) / GB / SECONDS_PER_MONTH
@@ -149,19 +197,19 @@ def ttl_scan_from_histograms(
         n = np.asarray([
             cost_model.egress_price(src, dst) / GB for (src, dst) in targets
         ])
-    # Transfers in, the kernel, the eager epilogue and the copy back.
+    # One float32 buffer in, one device program, one copy back.
     with tracing.span("skystore.ttl.scan"):
-        _ttl32, _cost32, surface = ttl_scan(
-            hist, time_w, last, edges, s, n, first,
-            use_kernel=(engine == "kernel"), interpret=interpret,
-        )
-        surface = np.asarray(surface, dtype=np.float64)
+        surface = ttl_refresh_surface(
+            pack_ttl_inputs(rows, first, s, n), device_edges(edges),
+            n_rows=rows.shape[0], use_kernel=(engine == "kernel"),
+            interpret=interpret)
+        surface = np.asarray(surface).astype(np.float64)
 
-    def exact(rows):
+    def exact(near):
         # Near-tie rows are resolved, and reported, in float64.
-        _, cost = batched_cost_curves(hist[rows], time_w[rows], last[rows],
-                                      edges, first[rows], s[rows], n[rows])
-        surface[rows] = cost
+        _, cost = batched_cost_curves(hist[near], time_w[near], last[near],
+                                      edges, first[near], s[near], n[near])
+        surface[near] = cost
         return cost
 
     with tracing.span("skystore.ttl.resolve"):

@@ -21,8 +21,9 @@ The kernel avoids `jnp.cumsum` (which lowers to a serial loop on some
 backends) in favour of a ceil(log2(C)) Hillis-Steele shift-add scan: 10
 shifted adds over the lane axis at C_PAD=896, each a full-width VPU op.
 
-Oracle: :func:`repro.kernels.ref.ttl_cost_ref`; jit wrapper + argmin epilogue:
-:func:`repro.kernels.ops.ttl_scan`.
+Oracle: :func:`repro.kernels.ref.ttl_cost_ref`.  A refresh runs this kernel
+inside one jitted program with its packed inputs and the TTL=0 column:
+:func:`repro.kernels.ops.ttl_refresh_surface`.
 """
 
 from __future__ import annotations

@@ -5,7 +5,9 @@ described, not attached.  It refuses what the chip would refuse and the
 interpreter lets through: slices not aligned to the tiling, more fast memory
 than a kernel may use.  These tests compile the refresh loop's kernel at the
 shapes the store runs -- a 3- and a 9-region refresh, and the §6.7.3 batch
-of 1024 edge problems -- and check that the kernel is really in the program.
+of 1024 edge problems -- and check that the kernel is really in the program;
+and they compile a whole refresh, inputs to surface, as the one program the
+refresh loop runs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.ops import ttl_refresh_surface
 from repro.kernels.ttl_scan import ttl_cost_surface
 
 
@@ -60,3 +63,18 @@ def test_ttl_cost_surface_compiles_for_v5e(one_chip, no_persistent_cache,
     compiled = ttl_cost_surface.lower(
         rows, rows, rows, f32(c_dim), per_edge, per_edge, per_edge).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("e_dim,c_dim", [(2, 800), (8, 800)])
+def test_ttl_refresh_is_one_program_for_v5e(one_chip, no_persistent_cache,
+                                           e_dim, c_dim):
+    """A refresh's packed inputs, the Pallas surface and the TTL=0 column
+    compile to one program with the kernel inside it."""
+    packed = jax.ShapeDtypeStruct((3 * c_dim + 3 * e_dim,), jnp.float32,
+                                  sharding=one_chip)
+    edges = jax.ShapeDtypeStruct((c_dim,), jnp.float32, sharding=one_chip)
+    compiled = ttl_refresh_surface.lower(packed, edges, n_rows=1).compile()
+    text = compiled.as_text()
+    assert text.count("HloModule ") == 1
+    assert "tpu_custom_call" in text
+    assert compiled.out_info.shape == (e_dim, c_dim + 1)

@@ -202,3 +202,99 @@ def test_ttl_scan_non_pow2_c_vs_ref(c_dim):
     assert surface_k.shape == (9, c_dim)
     np.testing.assert_allclose(np.asarray(surface_k), np.asarray(surface_r),
                                rtol=2e-5, atol=1e-4)
+
+
+def _eager_surface(hist, time_w, last, edges, s, n, first, use_kernel):
+    """The refresh surface op by op, one eager dispatch each: the reference
+    the one-program :func:`ops.ttl_refresh_surface` must reproduce."""
+    hist, time_w, last, edges, s, n, first = (
+        jnp.asarray(x, jnp.float32)
+        for x in (hist, time_w, last, edges, s, n, first))
+    if use_kernel:
+        surface = ttl_cost_surface(hist, time_w, last, edges, s, n, first,
+                                   interpret=True)
+    else:
+        surface = ref.ttl_cost_ref(hist, time_w, last, edges, s, n, first)
+    zero = (first + hist.sum(axis=1)) * n
+    return jnp.concatenate([zero[:, None], surface], axis=1)
+
+
+def _replay_histograms(e_dim, seed):
+    """``e_dim`` target-side histograms as a replay builds them: an empty
+    one (a pair still in warm-up), one whose re-reads all come long after
+    T_even (TTL=0 wins), then random gap and paused-byte censuses."""
+    from repro.core.histogram import AccessHistogram
+
+    rng = np.random.default_rng(seed)
+    out = [AccessHistogram.empty()]
+    evict = AccessHistogram.empty()
+    evict.add_gaps(rng.uniform(2e7, 4e7, 40), rng.uniform(1e6, 1e8, 40))
+    evict.add_last(rng.uniform(1, 1e4, 40), rng.uniform(1e8, 1e9, 40))
+    evict.add_first_read(1e8, remote=True)
+    out.append(evict)
+    while len(out) < e_dim:
+        h = AccessHistogram.empty()
+        k = int(rng.integers(32, 400))
+        h.add_gaps(rng.lognormal(9, 3, k), rng.uniform(1e3, 1e9, k))
+        h.add_last(rng.lognormal(10, 3, k // 2), rng.uniform(1e3, 1e9, k // 2))
+        h.add_first_read(float(rng.uniform(1e6, 1e9)), remote=True)
+        out.append(h)
+    return out[:e_dim]
+
+
+@pytest.mark.parametrize("engine", ["kernel", "jax"])
+@pytest.mark.parametrize("e_dim", [1, 8, 17])
+def test_ttl_refresh_is_one_program(e_dim, engine):
+    """The one jitted refresh program gives the op-by-op surface, and the
+    indices the refresh path chooses from it are the float64 argmin's, on
+    separate rows and on one histogram shared by every edge."""
+    from repro.core.costmodel import GB, SECONDS_PER_MONTH, pick_regions
+    from repro.core.ttl_policy import batched_cost_curves
+    from repro.kernels import ops
+
+    use_kernel = engine == "kernel"
+    prob = _hist_problem(e_dim, 800, seed=e_dim + 500)
+    _, _, full = ttl_scan(*prob, use_kernel=use_kernel, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(full), np.asarray(_eager_surface(*prob, use_kernel)),
+        rtol=2e-5)
+
+    cost = pick_regions(9)
+    names = cost.region_names()
+    pairs = [(a, b) for b in names for a in names if a != b][:e_dim]
+    hists = _replay_histograms(e_dim, seed=e_dim)
+    for rows in (hists, [hists[-1]] * e_dim):
+        ttls, _, _ = ops.ttl_scan_from_histograms(
+            rows, cost, pairs, engine=engine, interpret=True)
+        s = np.asarray([cost.storage_price(d) / GB / SECONDS_PER_MONTH
+                        for _, d in pairs])
+        n = np.asarray([cost.egress_price(a, d) / GB for a, d in pairs])
+        grid, cost64 = batched_cost_curves(
+            np.stack([h.hist for h in rows]),
+            np.stack([h.time_weight for h in rows]),
+            np.stack([h.last for h in rows]), rows[0].edges,
+            np.asarray([h.first_read_remote_bytes for h in rows]), s, n)
+        np.testing.assert_array_equal(ttls, grid[np.argmin(cost64, axis=1)])
+        if rows is hists and e_dim > 1:
+            # The evict-at-once row is a strict TTL=0 win.
+            assert cost64[1, 0] < cost64[1, 1:].min()
+
+
+def test_ttl_refreshes_of_one_shape_compile_once():
+    """A controller's refreshes of one (E, C) reuse one compiled program:
+    the first compiles it (``ttl.scan_compiles``), the second does not."""
+    from repro.core.costmodel import pick_regions
+    from repro.core.ttl_policy import AdaptiveTTLController
+
+    # A cell layout no other test uses, so the first refresh compiles.
+    edges = np.cumsum(np.linspace(1.0, 5e4, 37))
+    ctl = AdaptiveTTLController(pick_regions(3), refresh_period=10.0,
+                                warmup_min_samples=4, edges=edges,
+                                engine="jax")
+    rng = np.random.default_rng(0)
+    for now in (100.0, 200.0):
+        for dt, size in zip(rng.uniform(1, 1e5, 16), rng.uniform(1e3, 1e8, 16)):
+            ctl.record_gap("b", "aws:us-east-1", dt, size)
+        ctl.edge_ttl("b", "azure:eastus", "aws:us-east-1", now)
+    assert ctl.n_refreshes == ctl.n_device_scans == 2
+    assert ctl.n_scan_compiles == 1

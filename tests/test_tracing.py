@@ -1,7 +1,7 @@
 """The in-program span recorder (repro.core.tracing): recording changes no
 decision, leaves no method patched, refuses a span table that names a
 missing method, does exact self-time arithmetic, and sees the routing
-counters and the S3 request path."""
+counters, the TTL scan counters and the S3 request path."""
 
 import http.client
 import threading
@@ -185,6 +185,27 @@ def test_routing_counters(plane, routing, trace9, cost9):
         assert hinted == 0
     else:
         assert hinted > 0
+
+
+@pytest.mark.parametrize("plane", sorted(PLANES))
+@pytest.mark.parametrize("engine", ["jax", "numpy"])
+def test_ttl_scan_counters(plane, engine, trace9, cost9):
+    """Each refresh the jax engine solves is one device program, counted
+    in ``ttl.device_scans``; the numpy engine runs none."""
+    tracing.start()
+    try:
+        run = PLANES[plane](trace9, cost9, "skystore", engine=engine)
+    finally:
+        snap = tracing.stop()
+    n = run.policy.ctl.n_refreshes
+    assert n > 0
+    scans = snap.counters.get("ttl.device_scans", 0)
+    compiles = snap.counters.get("ttl.scan_compiles", 0)
+    if engine == "jax":
+        assert scans == n
+        assert compiles <= 1
+    else:
+        assert scans == compiles == 0
 
 
 def test_s3_request_holds_the_dispatch_on_the_proxy_thread():
