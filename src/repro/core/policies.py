@@ -435,18 +435,23 @@ class SkyStorePolicy(Policy):
         u_perf_val_per_gb: float = 0.0,
         size_stratified: bool = False,
         engine: str = "auto",
+        refresh_schedule: str = "access",
     ):
         super().__init__(cost)
         self.size_stratified = size_stratified
         # ``engine`` selects the controller's TTL refresh implementation
         # (repro.core.ttl_policy.TTL_ENGINES); plumbed through so whole-plane
         # replays can pin kernel decisions == scalar-python decisions (see
-        # tests/test_kernel_plane_equivalence.py).
+        # tests/test_kernel_plane_equivalence.py).  ``refresh_schedule``
+        # (repro.core.ttl_policy.REFRESH_SCHEDULES) says whether a pair's
+        # TTLs are re-solved by its first read after each refresh period or
+        # all together at every maintenance tick.
         self._ctl_kwargs = dict(
             refresh_period=refresh_period,
             warmup_min_samples=warmup_min_samples,
             u_perf_val_per_gb=u_perf_val_per_gb,
             engine=engine,
+            refresh_schedule=refresh_schedule,
         )
         self.ctl = self._mk()
 
@@ -500,7 +505,8 @@ class SkyStorePolicy(Policy):
 
     def periodic(self, now: float, sim) -> None:
         # Refresh the `last` histograms from the simulator's last-access maps
-        # (the §4.2 "background process ... once per day").
+        # (the §4.2 "background process ... once per day"); on the "tick"
+        # refresh schedule, then re-solve every warm pair's TTLs.
         for (bucket, region), entries in sim.last_access_snapshot().items():
             if not entries:
                 continue
@@ -511,6 +517,8 @@ class SkyStorePolicy(Policy):
                 ages = np.asarray([now - t for (t, _s) in vals])
                 sizes = np.asarray([_s for (_t, _s) in vals])
                 self.ctl.set_last_snapshot(bkey, region, ages, sizes)
+        if self.ctl.refresh_schedule == "tick":
+            self.ctl.sweep(now)
 
 
 # ---------------------------------------------------------------------------

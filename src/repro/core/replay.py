@@ -279,6 +279,9 @@ def _publish_counters(plane, expiry, policy) -> None:
     if ctl is not None:
         tracing.count("ttl.device_scans", ctl.n_device_scans)
         tracing.count("ttl.scan_compiles", ctl.n_scan_compiles)
+        tracing.count("ttl.sweeps", ctl.n_sweeps)
+        tracing.count("ttl.sweep_pairs", ctl.n_sweep_pairs)
+        tracing.count("ttl.sweep_rows", ctl.n_sweep_rows)
 
 
 class _ReplayBackend(InMemoryBackend):

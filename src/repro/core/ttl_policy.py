@@ -213,6 +213,32 @@ class EdgeTTL:
 #:   auto    kernel on TPU, numpy everywhere else.
 TTL_ENGINES = ("auto", "kernel", "jax", "numpy", "python")
 
+#: When a (bucket, region) pair's incoming edges are re-solved:
+#:
+#:   access  lazily, by the first read of the pair after its
+#:           ``refresh_period`` has run out (one refresh per pair);
+#:   tick    at every maintenance tick, after the paused-bytes census: one
+#:           sweep solves every pair past warm-up in one engine call.
+REFRESH_SCHEDULES = ("access", "tick")
+
+#: Pairs per batched float64 call of a numpy-engine sweep: bounds the
+#: ``[pairs x edges, cells]`` temporaries without changing a result (every
+#: row of :func:`batched_cost_curves` is computed on its own).
+SWEEP_CHUNK_PAIRS = 256
+
+
+def sweep_prices(cost: CostModel, dsts) -> Tuple[np.ndarray, np.ndarray]:
+    """Per pair of a sweep, by its region ``dsts[i]``: the storage price
+    there ($ per byte-second, ``[P]``) and the egress price of each
+    incoming edge, sources in catalog order ($ per byte, ``[P, k]``)."""
+    names = cost.region_names()
+    by_dst = {dst: (cost.storage_price(dst) / GB / SECONDS_PER_MONTH,
+                    [cost.egress_price(src, dst) / GB
+                     for src in names if src != dst])
+              for dst in dict.fromkeys(dsts)}
+    return (np.asarray([by_dst[d][0] for d in dsts]),
+            np.asarray([by_dst[d][1] for d in dsts]).reshape(len(dsts), -1))
+
 
 class AdaptiveTTLController:
     """Per-(bucket, target region) statistics -> per-edge TTLs (§3.3.1).
@@ -226,9 +252,10 @@ class AdaptiveTTLController:
     The refresh loop is *batched* (§6.7.3: 10 regions x 1000 buckets = 100k
     edge problems per cycle): all incoming edges of one (bucket, dst) pair are
     solved in a single call to the selected ``engine`` instead of one Python
-    argmin per edge.  TTLs are always resolved by argmin *index* against the
-    float64 candidate grid, so engine choice never leaks float32 TTL values
-    into the planes.
+    argmin per edge, and under ``refresh_schedule="tick"`` every warm pair of
+    the deployment in one call (:meth:`sweep`).  TTLs are always resolved by
+    argmin *index* against the float64 candidate grid, so engine choice never
+    leaks float32 TTL values into the planes.
     """
 
     def __init__(
@@ -240,6 +267,7 @@ class AdaptiveTTLController:
         edges: Optional[np.ndarray] = None,
         rotate_multiple_of_t_even: float = 2.0,
         engine: str = "auto",
+        refresh_schedule: str = "access",
     ) -> None:
         self.cost = cost
         self.refresh_period = refresh_period
@@ -250,22 +278,34 @@ class AdaptiveTTLController:
         self.edge_ttls: Dict[Tuple[str, str, str], EdgeTTL] = {}
         self.last_refresh: Dict[Tuple[str, str], float] = {}
         # (bucket, dst) -> (last_refresh stamp, {src: ttl}): edge TTLs only
-        # move inside _maybe_refresh, so a whole destination's incoming-edge
-        # table can be served from cache between refresh windows (see
-        # edge_ttl_table).
-        self._ttl_tables: Dict[Tuple[str, str], Tuple[float, Dict[str, float]]] = {}
+        # move inside _maybe_refresh or sweep, so a whole destination's
+        # incoming-edge table can be served from cache between refreshes
+        # (see edge_ttl_table).
+        self._ttl_tables: Dict[Tuple[str, str],
+                               Tuple[Optional[float], Dict[str, float]]] = {}
         self.rotate_multiple = rotate_multiple_of_t_even
         if engine not in TTL_ENGINES:
             raise ValueError(f"unknown TTL engine {engine!r}; have {TTL_ENGINES}")
+        if refresh_schedule not in REFRESH_SCHEDULES:
+            raise ValueError(f"unknown refresh schedule {refresh_schedule!r}; "
+                             f"have {REFRESH_SCHEDULES}")
         self.engine = engine
+        self.refresh_schedule = refresh_schedule
+        self._lazy = refresh_schedule == "access"
         self._engine_resolved: Optional[str] = None
         #: Refreshes that solved edge TTLs (past warmup), all on the one
         #: engine :meth:`resolve_engine` pins.
         self.n_refreshes = 0
-        #: Of those, the ones the kernel or jax engine solved as one device
-        #: program, and the ones whose call compiled that program.
+        #: Refreshes and sweeps the kernel or jax engine solved on the device
+        #: (a refresh in one program, a sweep in one per 2**14 edge rows),
+        #: and the ones whose call compiled a program.
         self.n_device_scans = 0
         self.n_scan_compiles = 0
+        #: Sweeps that solved at least one pair, the pairs they solved, and
+        #: those pairs' edge rows (before any padding).
+        self.n_sweeps = 0
+        self.n_sweep_pairs = 0
+        self.n_sweep_rows = 0
 
     # -- statistics ingestion ------------------------------------------------
     def hist_for(self, bucket: str, region: str) -> RollingHistogram:
@@ -302,7 +342,8 @@ class AdaptiveTTLController:
     # -- TTL queries ----------------------------------------------------------
     def edge_ttl(self, bucket: str, src: str, dst: str, now: float) -> float:
         """TTL for the (src -> dst) edge; T_even warmup before enough samples."""
-        self._maybe_refresh(bucket, dst, now)
+        if self._lazy:
+            self._maybe_refresh(bucket, dst, now)
         e = self.edge_ttls.get((bucket, src, dst))
         if e is None:
             return self.cost.t_even_seconds(src, dst)
@@ -314,18 +355,23 @@ class AdaptiveTTLController:
         src, dst, now)`` would return, amortized across the per-GET callers.
 
         Edge TTLs only change inside :meth:`_maybe_refresh` (refresh or
-        rotate), which is gated on ``refresh_period``; between refreshes the
-        table is constant, so it is cached against the ``last_refresh``
-        stamp and the same period gate the scalar path applies.  This keeps
+        rotate), which is gated on ``refresh_period``, or inside
+        :meth:`sweep`; between refreshes the table is constant, so it is
+        cached against the ``last_refresh`` stamp (and, on the ``access``
+        schedule, the same period gate the scalar path applies).  This keeps
         refresh *timing* identical to per-edge ``edge_ttl`` calls: the first
-        read past the period boundary triggers the refresh either way."""
+        read past the period boundary triggers the refresh either way.  On
+        the ``tick`` schedule a read never refreshes: it gets the last
+        sweep's table, T_even on every edge before the pair's first solve."""
         key = (bucket, dst)
         cached = self._ttl_tables.get(key)
         if cached is not None:
             last, tbl = cached
-            if now - last < self.refresh_period and self.last_refresh.get(key) == last:
+            if self.last_refresh.get(key) == last and (
+                    not self._lazy or now - last < self.refresh_period):
                 return tbl
-        self._maybe_refresh(bucket, dst, now)
+        if self._lazy:
+            self._maybe_refresh(bucket, dst, now)
         edge_ttls, t_even = self.edge_ttls, self.cost.t_even_seconds
         tbl = {}
         for src in self.cost.regions:
@@ -333,7 +379,7 @@ class AdaptiveTTLController:
                 continue
             e = edge_ttls.get((bucket, src, dst))
             tbl[src] = t_even(src, dst) if e is None else e.ttl_seconds
-        self._ttl_tables[key] = (self.last_refresh[key], tbl)
+        self._ttl_tables[key] = (self.last_refresh.get(key), tbl)
         return tbl
 
     def object_ttl(
@@ -364,6 +410,16 @@ class AdaptiveTTLController:
             self._engine_resolved = eng
         return self._engine_resolved
 
+    def _scalar(self, engine: str) -> bool:
+        """Whether edges are solved one by one: the §3.3.2 perf-value lift
+        walks the per-edge curve beyond the argmin, so it stays on the
+        scalar implementation; engine="python" keeps the legacy loop
+        selectable as the equivalence oracle."""
+        return self.u_perf_val_per_gb > 0 or engine == "python"
+
+    def _srcs(self, dst: str) -> list:
+        return [src for src in self.cost.region_names() if src != dst]
+
     def _maybe_refresh(self, bucket: str, dst: str, now: float) -> None:
         key = (bucket, dst)
         last = self.last_refresh.get(key, -np.inf)
@@ -381,35 +437,39 @@ class AdaptiveTTLController:
             merged = roll.merged()
         if merged.n_samples < self.warmup_min_samples:
             return
-        s = self.cost.storage_price(dst)
-        srcs = [src for src in self.cost.region_names() if src != dst]
+        srcs = self._srcs(dst)
         engine = self.resolve_engine()
         self.n_refreshes += 1
-        if self.u_perf_val_per_gb > 0 or engine == "python":
-            # Scalar reference path: the §3.3.2 perf-value lift walks the
-            # per-edge curve beyond the argmin, so it stays on the scalar
-            # implementation; engine="python" keeps the legacy loop
-            # selectable as the equivalence oracle.
+        if self._scalar(engine):
             with tracing.span("skystore.ttl.scan"):
-                for src in srcs:
-                    n = self.cost.egress_price(src, dst)
-                    if self.u_perf_val_per_gb > 0:
-                        ttl = choose_ttl_with_perf_value(
-                            merged, s, n, self.u_perf_val_per_gb)
-                    else:
-                        ttl = choose_ttl(merged, s, n)
-                    _ttls_c, cost_c = expected_cost_curve(merged, s, n)
-                    self.edge_ttls[(bucket, src, dst)] = EdgeTTL(
-                        ttl, now, float(cost_c.min())
-                    )
+                self._solve_scalar(bucket, dst, merged, now)
         else:
             ttls, costs = self._refresh_batched(merged, dst, srcs, engine)
             for src, ttl, c in zip(srcs, ttls, costs):
                 self.edge_ttls[(bucket, src, dst)] = EdgeTTL(
                     float(ttl), now, float(c)
                 )
-        # Rotate the collection window once it is comfortably longer than the
-        # largest T_even of any incoming edge (§3.2.3 guidance).
+        self._rotate(roll, dst, now)
+
+    def _solve_scalar(self, bucket: str, dst: str, merged: AccessHistogram,
+                      now: float) -> None:
+        """Every incoming edge of (bucket, dst), one scalar argmin each."""
+        s = self.cost.storage_price(dst)
+        for src in self._srcs(dst):
+            n = self.cost.egress_price(src, dst)
+            if self.u_perf_val_per_gb > 0:
+                ttl = choose_ttl_with_perf_value(
+                    merged, s, n, self.u_perf_val_per_gb)
+            else:
+                ttl = choose_ttl(merged, s, n)
+            _ttls_c, cost_c = expected_cost_curve(merged, s, n)
+            self.edge_ttls[(bucket, src, dst)] = EdgeTTL(
+                ttl, now, float(cost_c.min())
+            )
+
+    def _rotate(self, roll: RollingHistogram, dst: str, now: float) -> None:
+        """Rotate the collection window once it is comfortably longer than
+        the largest T_even of any incoming edge (§3.2.3 guidance)."""
         t_even_max = max(
             self.cost.t_even_seconds(src, dst)
             for src in self.cost.region_names()
@@ -427,24 +487,11 @@ class AdaptiveTTLController:
         egress price N varies.  Returns ``(ttl_seconds [E], best_cost [E])``
         with TTLs off the float64 candidate grid on every engine.
         """
-        s_gbmo = self.cost.storage_price(dst)
-        n_gb = [self.cost.egress_price(src, dst) for src in srcs]
         if engine == "numpy":
-            e_dim = len(srcs)
-            with tracing.span("skystore.ttl.inputs"):
-                s = np.asarray([s_gbmo / GB / SECONDS_PER_MONTH] * e_dim)
-                n = np.asarray([x / GB for x in n_gb])
-                hist = np.broadcast_to(merged.hist,
-                                       (e_dim, merged.hist.shape[0]))
-                time_w = np.broadcast_to(merged.time_weight, hist.shape)
-                last = np.broadcast_to(merged.last, hist.shape)
-                first = np.full(e_dim, merged.first_read_remote_bytes)
-            with tracing.span("skystore.ttl.scan"):
-                ttls, cost = batched_cost_curves(
-                    hist, time_w, last, merged.edges, first, s, n)
-            with tracing.span("skystore.ttl.resolve"):
-                idx = np.argmin(cost, axis=1)
-                return ttls[idx], cost[np.arange(e_dim), idx]
+            # A refresh is a one-pair sweep (every row of
+            # batched_cost_curves is computed on its own).
+            ttls, costs = self._sweep_batched([merged], [dst], engine)
+            return ttls[0], costs[0]
         # kernel / jax: the float32 batched scan with float64 candidate
         # resolution (repro.kernels.ops canonicalizes argmin ties).
         from repro.kernels import ops
@@ -455,3 +502,79 @@ class AdaptiveTTLController:
         self.n_device_scans += 1
         self.n_scan_compiles += ops.ttl_scan_programs() - programs
         return np.asarray(ttls), np.asarray(costs)
+
+    # -- the maintenance tick's sweep ---------------------------------------------
+    def sweep(self, now: float) -> int:
+        """Re-solve every pair past warm-up (the ``tick`` schedule), after
+        the maintenance tick's paused-bytes census.
+
+        Each (bucket, region) pair's rolling histogram is merged; pairs with
+        fewer than ``warmup_min_samples`` samples are skipped.  Every
+        incoming edge of every other pair is solved in one engine call (the
+        kernel and jax engines: one device program; numpy: batched float64;
+        python and the §3.3.2 perf-value lift: the scalar loop), each solved
+        pair's ``last_refresh`` becomes ``now`` and its window rotates as a
+        refresh's would.  Returns the number of pairs solved."""
+        with tracing.span("skystore.ttl.sweep"):
+            with tracing.span("skystore.ttl.merge"):
+                warm = []
+                for key, roll in self.hists.items():
+                    merged = roll.merged()
+                    if merged.n_samples >= self.warmup_min_samples:
+                        warm.append((key, roll, merged))
+            if not warm:
+                return 0
+            engine = self.resolve_engine()
+            if self._scalar(engine):
+                with tracing.span("skystore.ttl.scan"):
+                    for (bucket, dst), _roll, merged in warm:
+                        self._solve_scalar(bucket, dst, merged, now)
+            else:
+                ttls, costs = self._sweep_batched(
+                    [m for _k, _r, m in warm], [k[1] for k, _r, _m in warm],
+                    engine)
+                edge_ttls = self.edge_ttls
+                for ((bucket, dst), _roll, _m), row_t, row_c in zip(
+                        warm, ttls.tolist(), costs.tolist()):
+                    for src, ttl, c in zip(self._srcs(dst), row_t, row_c):
+                        edge_ttls[(bucket, src, dst)] = EdgeTTL(ttl, now, c)
+            for key, roll, _merged in warm:
+                self.last_refresh[key] = now
+                self._rotate(roll, key[1], now)
+            self.n_sweeps += 1
+            self.n_sweep_pairs += len(warm)
+            self.n_sweep_rows += len(warm) * (len(self.cost.region_names()) - 1)
+            return len(warm)
+
+    def _sweep_batched(self, merged: list, dsts: list, engine: str
+                       ) -> Tuple[np.ndarray, np.ndarray]:
+        """Every incoming edge of the pairs ``(merged[i], dsts[i])``:
+        ``(ttl_seconds [P, k], best_cost [P, k])``, sources in catalog order,
+        TTLs off the float64 candidate grid on every engine."""
+        with tracing.span("skystore.ttl.inputs"):
+            rows = np.asarray([(m.hist, m.time_weight, m.last) for m in merged])
+            first = np.asarray([m.first_read_remote_bytes for m in merged])
+            s, n = sweep_prices(self.cost, dsts)
+        if engine != "numpy":
+            from repro.kernels import ops
+            programs = ops.ttl_scan_programs()
+            out = ops.ttl_sweep(rows, first, s, n, merged[0].edges,
+                                engine=engine)
+            self.n_device_scans += 1
+            self.n_scan_compiles += ops.ttl_scan_programs() - programs
+            return out
+        k = n.shape[1]
+        ttl_out, cost_out = np.empty((2, len(merged), k))
+        for lo in range(0, len(merged), SWEEP_CHUNK_PAIRS):
+            hi = min(lo + SWEEP_CHUNK_PAIRS, len(merged))
+            with tracing.span("skystore.ttl.scan"):
+                ttls, cost = batched_cost_curves(
+                    *np.repeat(rows[lo:hi], k, axis=0).transpose(1, 0, 2),
+                    merged[0].edges, np.repeat(first[lo:hi], k),
+                    np.repeat(s[lo:hi], k), n[lo:hi].ravel())
+            with tracing.span("skystore.ttl.resolve"):
+                idx = np.argmin(cost, axis=1)
+                ttl_out[lo:hi] = ttls[idx].reshape(-1, k)
+                cost_out[lo:hi] = cost[np.arange(idx.shape[0]), idx
+                                       ].reshape(-1, k)
+        return ttl_out, cost_out

@@ -5,13 +5,15 @@ edge) pair, the expected cost of all ~800 candidate TTLs and takes the argmin
 (§6.7.3: 10 regions x 1000 buckets = 100k edge problems per refresh).  That is
 the control-plane hot spot, and it is embarrassingly parallel over edges with
 a cumulative-sum structure over cells -- a natural VPU (8x128 vector unit)
-workload with zero MXU involvement.
+workload with zero MXU involvement.  A refresh solves the 8 incoming edges of
+one (bucket, region) pair; under the ``"tick"`` refresh schedule a sweep
+solves every warm pair's edges in one call (docs/ARCHITECTURE.md, "Kernel
+plane").
 
-TPU adaptation (DESIGN.md §5): we lay the histograms out as (edges x cells)
-tiles. Each grid step loads a (BLOCK_E, C_PAD) tile of the per-cell arrays
-into VMEM, computes two prefix and two suffix sums along the cell axis in
-fp32, forms the cost terms, and writes the (BLOCK_E, C_PAD) cost surface back
-to HBM.
+TPU adaptation: we lay the histograms out as (edges x cells) tiles.  Each
+grid step loads a (BLOCK_E, C_PAD) tile of the per-cell arrays into VMEM,
+computes two prefix and two suffix sums along the cell axis in fp32, forms
+the cost terms, and writes the (BLOCK_E, C_PAD) cost surface back to HBM.
 C_PAD rounds 800 up to the next multiple of 128 lanes -- 896 (7 x 128); block
 height defaults to 256 sublanes, so the working set is
 
@@ -21,9 +23,9 @@ The kernel avoids `jnp.cumsum` (which lowers to a serial loop on some
 backends) in favour of a ceil(log2(C)) Hillis-Steele shift-add scan: 10
 shifted adds over the lane axis at C_PAD=896, each a full-width VPU op.
 
-Oracle: :func:`repro.kernels.ref.ttl_cost_ref`.  A refresh runs this kernel
-inside one jitted program with its packed inputs and the TTL=0 column:
-:func:`repro.kernels.ops.ttl_refresh_surface`.
+Oracle: :func:`repro.kernels.ref.ttl_cost_ref`.  A refresh or a sweep runs
+this kernel inside one jitted program with its packed inputs and the TTL=0
+column: :func:`repro.kernels.ops.ttl_refresh_surface`.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ than a kernel may use.  These tests compile the refresh loop's kernel at the
 shapes the store runs -- a 3- and a 9-region refresh, and the §6.7.3 batch
 of 1024 edge problems -- and check that the kernel is really in the program;
 and they compile a whole refresh, inputs to surface, as the one program the
-refresh loop runs.
+refresh loop runs, and the largest program of a maintenance tick's sweep.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.ops import ttl_refresh_surface
+from repro.kernels.ops import SWEEP_MAX_EDGES, ttl_refresh_surface
 from repro.kernels.ttl_scan import ttl_cost_surface
 
 
@@ -74,6 +74,22 @@ def test_ttl_refresh_is_one_program_for_v5e(one_chip, no_persistent_cache,
                                   sharding=one_chip)
     edges = jax.ShapeDtypeStruct((c_dim,), jnp.float32, sharding=one_chip)
     compiled = ttl_refresh_surface.lower(packed, edges, n_rows=1).compile()
+    text = compiled.as_text()
+    assert text.count("HloModule ") == 1
+    assert "tpu_custom_call" in text
+    assert compiled.out_info.shape == (e_dim, c_dim + 1)
+
+
+def test_largest_sweep_program_compiles_for_v5e(one_chip, no_persistent_cache):
+    """A 9-region sweep's largest program: ``SWEEP_MAX_EDGES`` edge rows,
+    each pair's histogram row serving its 8 incoming edges, in one program
+    with the kernel inside it and within the chip's scoped VMEM."""
+    c_dim, e_dim = 800, SWEEP_MAX_EDGES
+    n_rows = e_dim // 8
+    packed = jax.ShapeDtypeStruct((n_rows * 3 * c_dim + 3 * e_dim,),
+                                  jnp.float32, sharding=one_chip)
+    edges = jax.ShapeDtypeStruct((c_dim,), jnp.float32, sharding=one_chip)
+    compiled = ttl_refresh_surface.lower(packed, edges, n_rows=n_rows).compile()
     text = compiled.as_text()
     assert text.count("HloModule ") == 1
     assert "tpu_custom_call" in text
