@@ -95,17 +95,19 @@ def kernel_phase(kind: str) -> None:
 
         _, _, full = ops.ttl_scan(*prob)
         full = np.asarray(full)
-        _, cost64 = batched_cost_curves(*prob[:4], prob[6], prob[4], prob[5])
+        grid, cost64 = batched_cost_curves(*prob[:4], prob[6], prob[4],
+                                           prob[5])
         want = np.argmin(cost64, axis=1)
         plain = int(np.sum(np.argmin(full, axis=1) == want))
-        idx = ops.canonical_argmin(full, prob[0], prob[2],
-                                   lambda rows: cost64[rows])
-        check(np.array_equal(idx, want),
-              f"E={e_dim}: canonical argmin != float64 argmin")
+        # The refresh loop's decision: a row per edge, decided on the chip.
+        ttl, _ = ops.ttl_sweep(np.stack(prob[:3], axis=1), prob[6], prob[4],
+                               prob[5][:, None], prob[3])
+        check(np.array_equal(ttl[:, 0], grid[want]),
+              f"E={e_dim}: device decision != float64 argmin")
         print(f"kernel: E={e_dim} C={CELLS} compiled for {kind} "
               f"(tpu_custom_call); matches the jnp oracle on the chip, max "
               f"rel diff {rel!r}; plain float32 argmin == float64 argmin on "
-              f"{plain}/{e_dim} rows, canonical argmin on {e_dim}/{e_dim}",
+              f"{plain}/{e_dim} rows, device decision on {e_dim}/{e_dim}",
               flush=True)
 
 

@@ -282,6 +282,7 @@ def _publish_counters(plane, expiry, policy) -> None:
         tracing.count("ttl.sweeps", ctl.n_sweeps)
         tracing.count("ttl.sweep_pairs", ctl.n_sweep_pairs)
         tracing.count("ttl.sweep_rows", ctl.n_sweep_rows)
+        tracing.count("ttl.near_tie_rows", ctl.n_near_tie_rows)
 
 
 class _ReplayBackend(InMemoryBackend):

@@ -306,6 +306,10 @@ class AdaptiveTTLController:
         self.n_sweeps = 0
         self.n_sweep_pairs = 0
         self.n_sweep_rows = 0
+        #: Edge rows the kernel or jax engine left to the host's float64
+        #: re-solve: their runner-up lay within ``REFINE_BAND`` of the
+        #: minimum.  The device decides every other row.
+        self.n_near_tie_rows = 0
 
     # -- statistics ingestion ------------------------------------------------
     def hist_for(self, bucket: str, region: str) -> RollingHistogram:
@@ -492,15 +496,16 @@ class AdaptiveTTLController:
             # batched_cost_curves is computed on its own).
             ttls, costs = self._sweep_batched([merged], [dst], engine)
             return ttls[0], costs[0]
-        # kernel / jax: the float32 batched scan with float64 candidate
-        # resolution (repro.kernels.ops canonicalizes argmin ties).
+        # kernel / jax: the float32 batched scan decided on the device, with
+        # near ties resolved in float64 on the host (repro.kernels.ops).
         from repro.kernels import ops
-        programs = ops.ttl_scan_programs()
-        ttls, costs, _surface = ops.ttl_scan_from_histograms(
+        programs, near = ops.ttl_scan_programs(), ops.near_tie_rows()
+        ttls, costs = ops.ttl_scan_from_histograms(
             [merged] * len(srcs), self.cost,
             [(src, dst) for src in srcs], engine=engine)
         self.n_device_scans += 1
         self.n_scan_compiles += ops.ttl_scan_programs() - programs
+        self.n_near_tie_rows += ops.near_tie_rows() - near
         return np.asarray(ttls), np.asarray(costs)
 
     # -- the maintenance tick's sweep ---------------------------------------------
@@ -557,11 +562,12 @@ class AdaptiveTTLController:
             s, n = sweep_prices(self.cost, dsts)
         if engine != "numpy":
             from repro.kernels import ops
-            programs = ops.ttl_scan_programs()
+            programs, near = ops.ttl_scan_programs(), ops.near_tie_rows()
             out = ops.ttl_sweep(rows, first, s, n, merged[0].edges,
                                 engine=engine)
             self.n_device_scans += 1
             self.n_scan_compiles += ops.ttl_scan_programs() - programs
+            self.n_near_tie_rows += ops.near_tie_rows() - near
             return out
         k = n.shape[1]
         ttl_out, cost_out = np.empty((2, len(merged), k))

@@ -29,8 +29,9 @@ from .ttl_scan import BLOCK_E, ttl_cost_surface
                    static_argnames=("n_rows", "use_kernel", "interpret"))
 def ttl_refresh_surface(packed: jax.Array, edges: jax.Array, n_rows: int,
                         use_kernel: bool = True, interpret: bool = False):
-    """One refresh or one sweep as one device program: the ``[E, C+1]``
-    float32 surface.
+    """The ``[E, C+1]`` float32 surface of one refresh or one sweep: the
+    body of the program the refresh loop runs (:func:`ttl_refresh_decision`)
+    and, called alone, one program of its own for :func:`ttl_scan`.
 
     ``packed`` is the float32 buffer :func:`pack_ttl_inputs` builds: ``R``
     rows of (re-read bytes, gap-weighted bytes, paused bytes) over the ``C``
@@ -62,10 +63,59 @@ def ttl_refresh_surface(packed: jax.Array, edges: jax.Array, n_rows: int,
     return jnp.concatenate([zero[:, None], surface], axis=1)
 
 
+def ttl_row_decision(surface: jax.Array, last_cell: jax.Array) -> jax.Array:
+    """What the host's float64 decision needs of each row of a ``[E, C+1]``
+    surface, as one float32 ``[3, E]`` array: the first-index argmin
+    ``idx`` of the row cut at its plateau, its value ``mn``, and
+    ``second``, the least value of the cut row with ``idx`` masked out (so
+    an exact tie gives ``second == mn``).
+
+    The plateau: past the last cell holding re-read or paused bytes
+    (``last_cell`` ``[E]``, -1 for a row holding none) nothing misses and
+    nothing is paused, so in float64 every later candidate costs exactly
+    what the plateau start (candidate ``last_cell + 1``) costs, and the
+    argmin never lies beyond it.  The float32 surface sums the same bytes
+    in a different grouping on every lane, so its plateau wobbles by a few
+    ulps; candidates past the plateau start are cut (``+inf``).  Candidate
+    0, TTL=0, is never cut."""
+    cols = jnp.arange(surface.shape[1], dtype=surface.dtype)
+    cut = jnp.where(cols > last_cell[:, None] + 1, jnp.inf, surface)
+    idx = jnp.argmin(cut, axis=1)
+    mn = jnp.min(cut, axis=1)
+    second = jnp.min(jnp.where(cols == idx[:, None], jnp.inf, cut), axis=1)
+    return jnp.stack([idx.astype(surface.dtype), mn, second])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("n_rows", "use_kernel", "interpret"))
+def ttl_refresh_decision(packed: jax.Array, edges: jax.Array, n_rows: int,
+                         use_kernel: bool = True, interpret: bool = False):
+    """One refresh or one sweep decided on the device, as one program: the
+    float32 ``[3, E]`` :func:`ttl_row_decision` of
+    :func:`ttl_refresh_surface`'s surface.
+
+    ``packed`` is :func:`ttl_refresh_surface`'s buffer followed by the
+    ``R`` (``n_rows``) rows' last held cells (:func:`last_held_cells`),
+    each serving the same edges as its row."""
+    split = packed.shape[0] - n_rows
+    surface = ttl_refresh_surface(packed[:split], edges, n_rows=n_rows,
+                                  use_kernel=use_kernel, interpret=interpret)
+    last_cell = jnp.repeat(packed[split:], surface.shape[0] // n_rows)
+    return ttl_row_decision(surface, last_cell)
+
+
 def ttl_scan_programs() -> int:
-    """How many programs :func:`ttl_refresh_surface` has compiled in this
-    process: one per (E, C, rows, engine)."""
-    return ttl_refresh_surface._cache_size()
+    """How many programs :func:`ttl_refresh_decision`, the program the
+    refresh loop and the sweep run, has compiled in this process: one per
+    (E, C, rows, engine)."""
+    return ttl_refresh_decision._cache_size()
+
+
+def near_tie_rows() -> int:
+    """How many edge rows the device engines have decided in float64 on
+    the host in this process: the rows whose runner-up lay within
+    :data:`REFINE_BAND` of their minimum."""
+    return _near_tie_rows
 
 
 def device_edges(edges) -> jax.Array:
@@ -79,12 +129,26 @@ def _device_edges(raw: bytes, dtype: str) -> jax.Array:
     return jax.device_put(np.frombuffer(raw, dtype).astype(np.float32))
 
 
-def pack_ttl_inputs(rows, first_remote, s_price, n_price) -> np.ndarray:
+def pack_ttl_inputs(rows, first_remote, s_price, n_price, *tail
+                    ) -> np.ndarray:
     """``rows`` ``[R, 3, C]`` and the three ``[E]`` per-edge values as the
-    one float32 host buffer :func:`ttl_refresh_surface` reads."""
+    one float32 host buffer :func:`ttl_refresh_surface` reads, then any
+    ``tail`` arrays (:func:`ttl_refresh_decision` reads the ``[R]`` last
+    held cells there)."""
     return np.concatenate(
         [np.ravel(rows), np.ravel(first_remote), np.ravel(s_price),
-         np.ravel(n_price)], dtype=np.float32, casting="same_kind")
+         np.ravel(n_price), *map(np.ravel, tail)], dtype=np.float32,
+        casting="same_kind")
+
+
+def last_held_cells(rows) -> np.ndarray:
+    """The last cell of each of ``rows`` ``[R, 3, C]`` that holds re-read
+    or paused bytes, -1 where none does: where each row's plateau starts,
+    one candidate later (:func:`ttl_row_decision`)."""
+    held = (rows[:, 0] > 0) | (rows[:, 2] > 0)                    # [R, C]
+    c_dim = held.shape[1]
+    return np.where(held.any(axis=1),
+                    c_dim - 1 - np.argmax(held[:, ::-1], axis=1), -1)
 
 
 def ttl_scan(
@@ -98,7 +162,8 @@ def ttl_scan(
     candidate 0 is TTL=0 (evict immediately) and candidate j+1 is
     TTL=edges[j].  All inputs may be numpy or jax arrays.  The surface is
     :func:`ttl_refresh_surface`'s; the float32 argmin is taken on it
-    afterwards (the refresh loop decides with :func:`canonical_argmin`).
+    afterwards (the refresh loop decides on the device's
+    :func:`ttl_refresh_decision` with :func:`_solve`).
     """
     edges = device_edges(edges)
     rows = np.stack([np.asarray(x) for x in (hist, time_w, last)], axis=1)
@@ -118,86 +183,71 @@ def ttl_scan(
 #: ties.  2**-16 leaves an order of magnitude of margin.
 REFINE_BAND = 2.0 ** -16
 
+_near_tie_rows = 0
 
-def canonical_argmin(surface: np.ndarray, hist: np.ndarray,
-                     last: np.ndarray, exact) -> np.ndarray:
-    """The float64 argmin of each row, decided from a float32 surface.
 
-    This is the decision rule both float32 engines share, so that the chosen
-    *index* -- and therefore the float64 candidate TTL it maps to -- is the
-    one the pure-float64 ``choose_ttl`` argmin picks.  ``hist`` and ``last``
-    hold one row per surface row, or one row per ``k`` consecutive surface
-    rows (a pair's histogram, shared by its incoming edges).  Two steps:
+def decide_rows(decision, candidates: np.ndarray, exact):
+    """Float64 ``(best_ttl, best_cost)`` of each row of a copied-back
+    :func:`ttl_row_decision` ``[3, E]``, on the candidate grid
+    ``candidates`` ``[C+1]``.
 
-    * Plateau.  Past the last cell holding re-read (``hist``) or paused
-      (``last``) bytes nothing misses and nothing is paused, so in float64
-      every later candidate costs exactly what the plateau start costs and
-      the argmin never lies beyond it.  The float32 scan sums the same bytes
-      in a different grouping on every lane, so its plateau wobbles by a few
-      ulps; the surface is cut at the plateau start.
-    * Near ties.  A row whose cut surface has more than one candidate within
-      :data:`REFINE_BAND` of its minimum takes the argmin of ``exact(rows)``,
-      the float64 surfaces of those rows.  Other rows take the float32
-      argmin, which the band proves is the float64 one.
-    """
-    surf = np.asarray(surface, dtype=np.float64)
-    held = (np.asarray(hist) > 0) | (np.asarray(last) > 0)        # [R, C]
-    c_dim = held.shape[1]
-    last_cell = np.where(held.any(axis=1),
-                         c_dim - 1 - np.argmax(held[:, ::-1], axis=1), -1)
-    last_cell = np.repeat(last_cell, surf.shape[0] // held.shape[0])
-    beyond = np.arange(surf.shape[1])[None, :] > last_cell[:, None] + 1
-    surf = np.where(beyond, np.inf, surf)
-    mn = surf.min(axis=1, keepdims=True)
-    idx = np.argmin(surf, axis=1)
-    rows = np.flatnonzero((surf <= mn * (1.0 + REFINE_BAND)).sum(axis=1) > 1)
-    if rows.size:
-        idx[rows] = np.argmin(exact(rows), axis=1)
-    return idx
+    A row whose runner-up lies within :data:`REFINE_BAND` of its minimum
+    -- more than one candidate float32 cannot order, an exact tie among
+    them -- takes the argmin of ``exact(rows)``, the float64 surfaces of
+    those rows, and its cost there.  Every other row takes the device's
+    argmin, which the band proves is the float64 one, and its float32
+    cost."""
+    idx, mn, second = np.asarray(decision)
+    ttl = candidates[idx.astype(np.intp)]
+    cost = mn.astype(np.float64)
+    near = np.flatnonzero(second.astype(np.float64)
+                          <= cost * (1.0 + REFINE_BAND))
+    if near.size:
+        full = exact(near)
+        j = np.argmin(full, axis=1)
+        ttl[near] = candidates[j]
+        cost[near] = full[np.arange(near.size), j]
+    return ttl, cost
 
 
 def _solve(problems, edges, engine: str, interpret: bool):
-    """Device surfaces and canonical argmins of one or more problems.
+    """Device decisions of one or more problems, completed on the host by
+    :func:`decide_rows`.
 
     Each problem is ``(rows, first, s, n, n_edges)``: ``rows`` ``[R, 3, C]``
     float64 (re-read, gap-weighted and paused bytes) serve the ``[E]``
     per-edge ``first``, ``s``, ``n`` as :func:`ttl_refresh_surface` lays
     them out, and edges past ``n_edges`` (with their rows) are padding,
     copied back and dropped before the decision.  Every program is started
-    before the first copy back.  Returns ``(best_ttl, best_cost, surface)``
-    of each problem's first ``n_edges`` edges, in float64."""
+    before the first copy back.  Returns ``(best_ttl, best_cost)`` of each
+    problem's first ``n_edges`` edges, in float64."""
     from repro.core.ttl_policy import batched_cost_curves
 
     if engine not in ("kernel", "jax"):
         raise ValueError(f"unknown ttl_scan engine {engine!r}")
     # One float32 buffer in, one device program, one copy back, a problem.
     with tracing.span("skystore.ttl.scan"):
-        out = [ttl_refresh_surface(
-                   pack_ttl_inputs(rows, first, s, n), device_edges(edges),
-                   n_rows=rows.shape[0], use_kernel=(engine == "kernel"),
-                   interpret=interpret)
+        out = [ttl_refresh_decision(
+                   pack_ttl_inputs(rows, first, s, n, last_held_cells(rows)),
+                   device_edges(edges), n_rows=rows.shape[0],
+                   use_kernel=(engine == "kernel"), interpret=interpret)
                for rows, first, s, n, _ in problems]
-        surfaces = [np.asarray(surface)[:p[4]].astype(np.float64)
-                    for surface, p in zip(out, problems)]
+        out = [np.asarray(o)[:, :p[4]] for o, p in zip(out, problems)]
     candidates = np.concatenate([[0.0], np.asarray(edges, dtype=np.float64)])
     results = []
     with tracing.span("skystore.ttl.resolve"):
-        for (rows, first, s, n, n_edges), surface in zip(problems, surfaces):
+        for (rows, first, s, n, _), decision in zip(problems, out):
             per_row = first.shape[0] // rows.shape[0]
 
             def exact(near):
-                # Near-tie rows are resolved, and reported, in float64.
+                # Near-tie rows are decided, and priced, in float64.
+                global _near_tie_rows
+                _near_tie_rows += near.size
                 r = rows[near // per_row]
-                _, cost = batched_cost_curves(r[:, 0], r[:, 1], r[:, 2],
-                                              edges, first[near], s[near],
-                                              n[near])
-                surface[near] = cost
-                return cost
+                return batched_cost_curves(r[:, 0], r[:, 1], r[:, 2], edges,
+                                           first[near], s[near], n[near])[1]
 
-            held = rows[:-(-n_edges // per_row)]
-            idx = canonical_argmin(surface, held[:, 0], held[:, 2], exact)
-            results.append((candidates[idx],
-                            surface[np.arange(n_edges), idx], surface))
+            results.append(decide_rows(decision, candidates, exact))
     return results
 
 
@@ -215,10 +265,10 @@ def ttl_scan_from_histograms(
     ``engine``     -- "kernel" (Pallas) or "jax" (jnp oracle); defaults from
                       ``use_kernel`` for backward compatibility.
 
-    Returns ``(best_ttl [E], best_cost [E], cost_surface [E, C+1])`` as
-    float64 numpy arrays.  TTLs are resolved by canonical argmin *index*
-    against the float64 candidate grid ``[0, edges...]``, so the returned TTL
-    values are exact candidate boundaries, never float32 roundings of them.
+    Returns ``(best_ttl [E], best_cost [E])`` as float64 numpy arrays,
+    decided as :func:`_solve` decides: TTLs are picked by *index* on the
+    float64 candidate grid ``[0, edges...]``, so the returned TTL values are
+    exact candidate boundaries, never float32 roundings of them.
 
     Raises ``ValueError`` if the histograms do not share one cell layout
     (mirroring :meth:`AccessHistogram.merge`): a silent mismatch would price
@@ -296,8 +346,8 @@ def ttl_sweep(rows, first, s, n, edges, engine: str = "kernel",
     edges.  The pairs go to the device in one program per
     :func:`sweep_chunks` range, each padded with zero rows to
     :func:`sweep_rows` (padded edges have zero bytes and zero prices); the
-    padding is dropped before the decision, which is
-    :func:`canonical_argmin`'s, as for one refresh.
+    padding is dropped before the decision, which is :func:`_solve`'s, as
+    for one refresh.
 
     Returns ``(best_ttl [P, k], best_cost [P, k])`` as float64 arrays."""
     n_pairs, k = n.shape
@@ -313,8 +363,8 @@ def ttl_sweep(rows, first, s, n, edges, engine: str = "kernel",
             per_edge[2, :p] = n[lo:hi]
             problems.append((chunk, *per_edge.reshape(3, -1), p * k))
     out = _solve(problems, edges, engine, interpret)
-    ttls = np.concatenate([t for t, _c, _s in out])
-    costs = np.concatenate([c for _t, c, _s in out])
+    ttls = np.concatenate([t for t, _c in out])
+    costs = np.concatenate([c for _t, c in out])
     return ttls.reshape(-1, k), costs.reshape(-1, k)
 
 

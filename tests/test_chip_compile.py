@@ -6,7 +6,7 @@ interpreter lets through: slices not aligned to the tiling, more fast memory
 than a kernel may use.  These tests compile the refresh loop's kernel at the
 shapes the store runs -- a 3- and a 9-region refresh, and the §6.7.3 batch
 of 1024 edge problems -- and check that the kernel is really in the program;
-and they compile a whole refresh, inputs to surface, as the one program the
+and they compile a whole refresh, inputs to decision, as the one program the
 refresh loop runs, and the largest program of a maintenance tick's sweep.
 
 The topology is described inside a fixture, never at import: only one
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.ops import SWEEP_MAX_EDGES, ttl_refresh_surface
+from repro.kernels.ops import SWEEP_MAX_EDGES, ttl_refresh_decision
 from repro.kernels.ttl_scan import ttl_cost_surface
 
 
@@ -68,29 +68,32 @@ def test_ttl_cost_surface_compiles_for_v5e(one_chip, no_persistent_cache,
 @pytest.mark.parametrize("e_dim,c_dim", [(2, 800), (8, 800)])
 def test_ttl_refresh_is_one_program_for_v5e(one_chip, no_persistent_cache,
                                            e_dim, c_dim):
-    """A refresh's packed inputs, the Pallas surface and the TTL=0 column
-    compile to one program with the kernel inside it."""
-    packed = jax.ShapeDtypeStruct((3 * c_dim + 3 * e_dim,), jnp.float32,
+    """A refresh's packed inputs, the Pallas surface, the TTL=0 column and
+    each row's reduction compile to one program with the kernel inside it,
+    returning the ``[3, E]`` decision."""
+    packed = jax.ShapeDtypeStruct((3 * c_dim + 3 * e_dim + 1,), jnp.float32,
                                   sharding=one_chip)
     edges = jax.ShapeDtypeStruct((c_dim,), jnp.float32, sharding=one_chip)
-    compiled = ttl_refresh_surface.lower(packed, edges, n_rows=1).compile()
+    compiled = ttl_refresh_decision.lower(packed, edges, n_rows=1).compile()
     text = compiled.as_text()
     assert text.count("HloModule ") == 1
     assert "tpu_custom_call" in text
-    assert compiled.out_info.shape == (e_dim, c_dim + 1)
+    assert compiled.out_info.shape == (3, e_dim)
 
 
 def test_largest_sweep_program_compiles_for_v5e(one_chip, no_persistent_cache):
     """A 9-region sweep's largest program: ``SWEEP_MAX_EDGES`` edge rows,
     each pair's histogram row serving its 8 incoming edges, in one program
-    with the kernel inside it and within the chip's scoped VMEM."""
+    with the kernel inside it and within the chip's scoped VMEM, returning
+    the ``[3, E]`` decision."""
     c_dim, e_dim = 800, SWEEP_MAX_EDGES
     n_rows = e_dim // 8
-    packed = jax.ShapeDtypeStruct((n_rows * 3 * c_dim + 3 * e_dim,),
+    packed = jax.ShapeDtypeStruct((n_rows * (3 * c_dim + 1) + 3 * e_dim,),
                                   jnp.float32, sharding=one_chip)
     edges = jax.ShapeDtypeStruct((c_dim,), jnp.float32, sharding=one_chip)
-    compiled = ttl_refresh_surface.lower(packed, edges, n_rows=n_rows).compile()
+    compiled = ttl_refresh_decision.lower(packed, edges,
+                                          n_rows=n_rows).compile()
     text = compiled.as_text()
     assert text.count("HloModule ") == 1
     assert "tpu_custom_call" in text
-    assert compiled.out_info.shape == (e_dim, c_dim + 1)
+    assert compiled.out_info.shape == (3, e_dim)

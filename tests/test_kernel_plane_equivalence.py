@@ -17,6 +17,7 @@ just synthetic random problems:
 
 import functools
 
+import jax
 import numpy as np
 import pytest
 
@@ -172,36 +173,65 @@ def test_ttl_zero_wins_exactly(engine):
         assert ttl == 0.0, f"engine {eng} chose {ttl!r}, want exactly 0.0"
 
 
-def test_canonical_argmin_cuts_the_float32_plateau():
-    """Past the last cell holding bytes every candidate costs exactly the
-    plateau start in float64; float32 wobble there must not move the
-    decision off the plateau start, however wide it is."""
-    hist = np.zeros((1, 6))
-    last = np.zeros((1, 6))
-    hist[0, 1] = 5.0
-    last[0, 2] = 1.0              # last held cell 2 -> plateau starts at 3
-    wobble = 5.0 * (1.0 - 1e-5)
-    surface = np.array([[9.0, 8.0, 7.0, 5.0, wobble, 5.0, wobble]])
+#: A runner-up exactly at the band's edge above a minimum of 3 (exact in
+#: float32 and in float64), and one float32 ulp above it.
+AT_BAND = 3.0 * (1.0 + ops.REFINE_BAND)
+ABOVE_BAND = float(np.nextafter(np.float32(AT_BAND), np.float32(np.inf)))
+WOBBLE = 5.0 * (1.0 - 1e-5)
 
-    def exact(rows):
-        raise AssertionError(f"no near tie to resolve, got rows {rows}")
+#: Planted float32 surfaces: each row's last held cell, the float64
+#: surfaces the host must be asked for (``None``: none), the candidates
+#: chosen and the rows flagged as near ties.
+DECISION_CASES = [
+    pytest.param([[9.0, 8.0, 7.0, 5.0, WOBBLE, 5.0, WOBBLE]], [2], None,
+                 [3], [], id="plateau_cut"),
+    pytest.param([[3.0, 1.0, 1.0 + 1e-7, 2.0, 2.5],
+                  [3.0, 1.0, 1.5, 2.0, 2.5]], [3, 3],
+                 [[3.0, 1.0 + 2e-8, 1.0, 2.0, 2.5]], [2, 1], [0],
+                 id="near_tie_resolved_in_float64"),
+    pytest.param([[3.0, 1.0, 2.0, 1.0, 2.5]], [3],
+                 [[3.0, 1.0, 2.0, 1.0 - 1e-12, 2.5]], [3], [0],
+                 id="exact_duplicate_minimum"),
+    pytest.param([[5.0, 3.0, AT_BAND, 4.0]], [2],
+                 [[5.0, 3.0, 3.0 - 1e-9, 4.0]], [2], [0],
+                 id="runner_up_at_band"),
+    pytest.param([[5.0, 3.0, ABOVE_BAND, 4.0]], [2], None, [1], [],
+                 id="runner_up_one_ulp_above_band"),
+    pytest.param([[9.0, 6.0, 5.0, 5.0 * (1 - 1e-6), 5.0 * (1 - 2e-6)]], [1],
+                 None, [2], [], id="plateau_wobbles_past_last_held_cell"),
+    pytest.param([[4.0, 1.0, 1.0, 1.0]], [-1], None, [0], [],
+                 id="row_holding_nothing"),
+    pytest.param([[3.0, 1.0, 2.0, 2.5], [0.0] * 4, [0.0] * 4], [2, -1, -1],
+                 None, [1, 0, 0], [], id="padded_rows"),
+]
 
-    assert list(ops.canonical_argmin(surface, hist, last, exact)) == [3]
 
-
-def test_canonical_argmin_resolves_near_ties_in_float64():
-    """Candidates float32 cannot order (within REFINE_BAND of the minimum)
-    are decided by the float64 surfaces of those rows only."""
-    hist = np.ones((2, 4))
-    last = np.zeros((2, 4))
-    surface = np.array([[3.0, 1.0, 1.0 + 1e-7, 2.0, 2.5],
-                        [3.0, 1.0, 1.5, 2.0, 2.5]])
-    float64 = np.array([[3.0, 1.0 + 2e-8, 1.0, 2.0, 2.5]])
+@pytest.mark.parametrize("surface,last_cell,float64,want,flagged",
+                         DECISION_CASES)
+def test_device_decision_and_float64_resolve(surface, last_cell, float64,
+                                             want, flagged):
+    """The device's reduction of each planted float32 row (argmin of the
+    row cut at its plateau, minimum, runner-up), then the host's decision:
+    rows whose runner-up lies within REFINE_BAND of the minimum, and only
+    those, are decided and priced by their float64 surfaces; the others
+    keep the device's argmin and its float32 cost."""
+    surface = np.asarray(surface, dtype=np.float32)
+    decision = np.asarray(jax.jit(ops.ttl_row_decision)(
+        surface, np.asarray(last_cell, dtype=np.float32)))
+    assert decision.shape == (3, surface.shape[0])
     calls = []
 
     def exact(rows):
         calls.append(list(rows))
-        return float64
+        return np.asarray(float64)
 
-    assert list(ops.canonical_argmin(surface, hist, last, exact)) == [2, 1]
-    assert calls == [[0]]
+    grid = np.arange(surface.shape[1], dtype=np.float64)
+    ttl, cost = ops.decide_rows(decision, grid, exact)
+    assert list(ttl) == want
+    assert calls == ([flagged] if flagged else [])
+    for i, j in enumerate(want):
+        if i in flagged:
+            assert cost[i] == float64[flagged.index(i)][j]
+        else:
+            assert decision[0, i] == j
+            assert cost[i] == surface[i, j]

@@ -264,7 +264,7 @@ def test_ttl_refresh_is_one_program(e_dim, engine):
     pairs = [(a, b) for b in names for a in names if a != b][:e_dim]
     hists = _replay_histograms(e_dim, seed=e_dim)
     for rows in (hists, [hists[-1]] * e_dim):
-        ttls, _, _ = ops.ttl_scan_from_histograms(
+        ttls, _ = ops.ttl_scan_from_histograms(
             rows, cost, pairs, engine=engine, interpret=True)
         s = np.asarray([cost.storage_price(d) / GB / SECONDS_PER_MONTH
                         for _, d in pairs])

@@ -191,7 +191,9 @@ def test_routing_counters(plane, routing, trace9, cost9):
 @pytest.mark.parametrize("engine", ["jax", "numpy"])
 def test_ttl_scan_counters(plane, engine, trace9, cost9):
     """Each refresh the jax engine solves is one device program, counted
-    in ``ttl.device_scans``; the numpy engine runs none."""
+    in ``ttl.device_scans``, and the edge rows it left to the host's
+    float64 re-solve in ``ttl.near_tie_rows``; the numpy engine runs
+    none."""
     tracing.start()
     try:
         run = PLANES[plane](trace9, cost9, "skystore", engine=engine)
@@ -201,11 +203,14 @@ def test_ttl_scan_counters(plane, engine, trace9, cost9):
     assert n > 0
     scans = snap.counters.get("ttl.device_scans", 0)
     compiles = snap.counters.get("ttl.scan_compiles", 0)
+    near = snap.counters.get("ttl.near_tie_rows", 0)
+    assert near == run.policy.ctl.n_near_tie_rows
     if engine == "jax":
         assert scans == n
         assert compiles <= 1
+        assert near <= n * (len(cost9.region_names()) - 1)
     else:
-        assert scans == compiles == 0
+        assert scans == compiles == near == 0
 
 
 def test_s3_request_holds_the_dispatch_on_the_proxy_thread():

@@ -37,10 +37,12 @@ GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden", "replay")
 
 @pytest.fixture(autouse=True)
 def interpret_kernel(monkeypatch):
-    """Off-TPU the kernel engine's sweeps run the Pallas kernel under its
-    interpreter; the program itself never picks that."""
+    """Off-TPU the kernel engine's sweeps and refreshes run the Pallas
+    kernel under its interpreter; the program itself never picks that."""
     monkeypatch.setattr(ops, "ttl_sweep", functools.partial(
         ops.ttl_sweep, interpret=True))
+    monkeypatch.setattr(ops, "ttl_scan_from_histograms", functools.partial(
+        ops.ttl_scan_from_histograms, interpret=True))
 
 
 def _fill(ctl, cost, n_pairs, seed):
@@ -124,25 +126,60 @@ def test_sweep_chooses_each_pairs_float64_argmin(monkeypatch, engine,
         assert exact_rows
 
 
+@pytest.mark.parametrize("n_regions", [3, 9])
+@pytest.mark.parametrize("schedule", ["tick", "access"])
+@pytest.mark.parametrize("engine", ["jax", "kernel"])
+def test_device_engines_decide_as_numpy(engine, schedule, n_regions):
+    """A sweep (``tick``) and one refresh a pair (``access``), decided on
+    the device, leave the numpy engine's TTL on every edge, and its cost:
+    exactly on the rows re-solved in float64 on the host, within the
+    float32 rounding the near-tie band rests on elsewhere."""
+    cost = pick_regions(n_regions)
+    ctls = {}
+    for eng in ("numpy", engine):
+        ctl = ctls[eng] = AdaptiveTTLController(
+            cost, warmup_min_samples=WARMUP, engine=eng,
+            refresh_schedule=schedule)
+        pairs = _fill(ctl, cost, 17, seed=n_regions)
+        if schedule == "tick":
+            ctl.sweep(DAY)
+        else:
+            for bucket, dst in pairs:
+                ctl.edge_ttl_table(bucket, dst, DAY)
+    want, got = ctls["numpy"].edge_ttls, ctls[engine].edge_ttls
+    assert want and set(got) == set(want)
+    for key, e in want.items():
+        assert got[key].ttl_seconds == e.ttl_seconds, key
+        np.testing.assert_allclose(got[key].expected_cost, e.expected_cost,
+                                   rtol=ops.REFINE_BAND / 2)
+    same = sum(got[k].expected_cost == e.expected_cost
+               for k, e in want.items())
+    # The T_even pair's tie is re-solved on the host.
+    assert 1 <= ctls[engine].n_near_tie_rows <= same
+    assert ctls["numpy"].n_near_tie_rows == 0
+
+
 def test_padding_rows_never_reach_a_decision(monkeypatch):
-    """Poison every padding row of the device surface with -inf, which
-    would win any argmin it reached: the decisions do not move."""
+    """Poison every padding row of the device's decision with a -inf
+    minimum and runner-up, which would win any argmin and force a float64
+    re-solve if it reached one: the decisions do not move."""
     cost = pick_regions(9)
     ctl = AdaptiveTTLController(cost, warmup_min_samples=WARMUP,
                                 engine="jax", refresh_schedule="tick")
     pairs = _fill(ctl, cost, 7, seed=3)
     want = _device_sweep(ctl, pairs)
-    orig = ops.ttl_refresh_surface
+    orig = ops.ttl_refresh_decision
     seen = []
 
     def poisoned(packed, edges, n_rows, **kw):
         out = orig(packed, edges, n_rows=n_rows, **kw)
-        seen.append((n_rows, out.shape[0]))
-        return out.at[len(pairs) * 8:].set(-jnp.inf)
+        seen.append((n_rows, out.shape))
+        pad = slice(len(pairs) * 8, None)
+        return out.at[0, pad].set(0.0).at[1:, pad].set(-jnp.inf)
 
-    monkeypatch.setattr(ops, "ttl_refresh_surface", poisoned)
+    monkeypatch.setattr(ops, "ttl_refresh_decision", poisoned)
     got = _device_sweep(ctl, pairs)
-    assert seen == [(BLOCK_E // 8, BLOCK_E)]
+    assert seen == [(BLOCK_E // 8, (3, BLOCK_E))]
     for a, b in zip(want, got):
         np.testing.assert_array_equal(a, b)
     assert np.isfinite(got[1]).all()
@@ -241,13 +278,13 @@ def test_a_sweep_split_over_programs_decides_the_same(monkeypatch):
     pairs = _fill(ctl, cost, 40, seed=5)
     want = _device_sweep(ctl, pairs)
     calls = []
-    orig = ops.ttl_refresh_surface
+    orig = ops.ttl_refresh_decision
 
     def counted(packed, edges, n_rows, **kw):
         calls.append(n_rows)
         return orig(packed, edges, n_rows=n_rows, **kw)
 
-    monkeypatch.setattr(ops, "ttl_refresh_surface", counted)
+    monkeypatch.setattr(ops, "ttl_refresh_decision", counted)
     monkeypatch.setattr(ops, "SWEEP_MAX_EDGES", BLOCK_E)
     got = _device_sweep(ctl, pairs)
     assert calls == [32, 32]
